@@ -12,6 +12,8 @@ import os
 from typing import Dict, List
 
 from benchmarks.common import CsvOut
+from repro.distributed.hlo_analysis import device_peaks
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                           "dryrun")
@@ -47,7 +49,7 @@ def format_table(recs: List[Dict]) -> str:
         lb_bytes = (mem.get("argument_size_in_bytes", 0)
                     + mem.get("output_size_in_bytes", 0)
                     + mem.get("temp_size_in_bytes", 0))
-        lb_s = lb_bytes / 819e9
+        lb_s = lb_bytes / device_peaks(PRODUCTION_DEVICE_KIND)["hbm_bw"]
         lines.append(
             f"{r['arch']:24s} {r['shape']:12s} {t['compute_s']:10.3e} "
             f"{t['memory_s']:10.3e} {lb_s:9.3e} {t['collective_s']:10.3e} "

@@ -30,6 +30,7 @@ import pathlib
 import sys
 
 from benchmarks.common import CsvOut
+from repro.launch.compile_cache import enable_compile_cache
 
 PHASE_JSON = (pathlib.Path(__file__).resolve().parent.parent
               / "experiments" / "bench_phases.json")
@@ -54,6 +55,7 @@ def main() -> None:
     p.add_argument("--trace", default=None, metavar="FILE",
                    help="also export the full Chrome trace.json")
     args = p.parse_args()
+    enable_compile_cache()
     steps = min(args.steps, 3) if args.quick else args.steps
     sft_steps = 10 if args.quick else 150
 

@@ -5,8 +5,8 @@ Two operating modes:
 * ``AsyncOrchestrator`` — real threads: a rollout worker continuously pulls
   the latest weights, generates groups, and pushes version-stamped batches;
   the trainer consumes fresh batches and publishes new weights. This is the
-  AReaL architecture in miniature (on one host the engines time-share the
-  device; on the production mesh they own disjoint pod slices).
+  AReaL architecture in miniature. Both engines time-share the devices of
+  one process; there is no placement of rollout on a disjoint slice.
 
 * ``simulate_async`` — deterministic single-thread simulation with an
   explicit staleness schedule. Used by tests and by the sync-vs-async

@@ -1,9 +1,9 @@
 """Versioned weight store — the trainer->rollout weight-sync channel.
 
 In AReaL this is an NCCL broadcast between GPU pools; here it is a lock-
-protected (version, params) cell. On a real multi-pod TPU deployment the
-publish is a ``jax.device_put`` onto the rollout pod slice's mesh (see
-launch/train.py).
+protected (version, params) cell. Trainer and rollout engine run in one
+process on the same devices, so a publish hands over the trainer's arrays
+as they are: nothing is copied or moved between meshes.
 """
 from __future__ import annotations
 

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Dict, Optional, Sequence, Tuple, Union
+from typing import Callable, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
@@ -18,22 +18,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AxisRule = Tuple[str, Union[str, Tuple[str, ...], None]]
 
-
-def abstract_mesh(axis_sizes: Sequence[int],
-                  axis_names: Sequence[str]) -> "jax.sharding.AbstractMesh":
-    """Version-portable ``AbstractMesh`` constructor.
-
-    jax <= 0.4.x takes a tuple of ``(name, size)`` pairs; newer releases
-    take ``(axis_sizes, axis_names)``. Feeding the new calling convention
-    to the old constructor leaves the mesh shape as a bare int, which is
-    the ``TypeError: 'int' object is not iterable`` failure mode — so we
-    normalize here instead of at every call site.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 # Default logical->mesh mapping. "embed" is the FSDP axis (weight d_model
 # dims sharded over data); activations use "act_embed" which is never
@@ -138,6 +122,26 @@ def use_sharding(env: Optional[ShardingEnv]):
         yield env
     finally:
         _LOCAL.env = prev
+
+
+def map_batch_shards(fn: Callable, *args, batch_args: Sequence[bool]):
+    """Run ``fn`` on each device's shard of the batch axis.
+
+    Mosaic (Pallas TPU) kernels cannot be partitioned by XLA, so a kernel
+    call inside a program over a multi-device mesh goes through
+    ``shard_map``: arguments flagged in ``batch_args`` are split along
+    their leading axis over the mesh axes of the "batch" rule, the rest
+    are replicated, and every output is split like the batch. Outside a
+    mesh (or on one device) this is a plain call.
+    """
+    env = current_env()
+    if env is None or env.mesh.size == 1:
+        return fn(*args)
+    axes = env._mesh_axes_for("batch")
+    spec = P(axes if len(axes) > 1 else axes[0]) if axes else P()
+    in_specs = tuple(spec if b else P() for b in batch_args)
+    return jax.shard_map(fn, mesh=env.mesh, in_specs=in_specs,
+                         out_specs=spec, check_vma=False)(*args)
 
 
 def constrain(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:

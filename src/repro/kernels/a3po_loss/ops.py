@@ -14,6 +14,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharding import map_batch_shards
 from repro.kernels.a3po_loss.kernel import a3po_loss_pallas
 from repro.kernels.a3po_loss.ref import a3po_loss_ref
 
@@ -24,8 +25,10 @@ def _run_fused(static, logp, behav_logp, alpha, adv, mask):
     flat = lambda x: x.astype(jnp.float32).reshape(-1)  # noqa: E731
     args = (flat(logp), flat(behav_logp), flat(alpha), flat(adv), flat(mask))
     if use_kernel:
-        outs = a3po_loss_pallas(*args, clip_eps=clip_eps, iw_cap=iw_cap,
-                                interpret=interpret)
+        kernel = functools.partial(a3po_loss_pallas, clip_eps=clip_eps,
+                                   iw_cap=iw_cap, interpret=interpret)
+        # Mosaic kernels are not auto-partitioned: one call per batch shard
+        outs = map_batch_shards(kernel, *args, batch_args=(True,) * 5)
     else:
         outs = a3po_loss_ref(*args, clip_eps=clip_eps, iw_cap=iw_cap)
     return tuple(o.reshape(lead) for o in outs)

@@ -31,7 +31,7 @@ def paged_decode_attention_op(q: jax.Array, pool_k: jax.Array,
                               interpret: bool = False) -> jax.Array:
     """Block-table-aware decode attention over one layer's paged pool.
 
-    q [S,H,hd]; pool_k/v [n_blocks,bs,KV,hd]; block_tables [S,max_blocks]
+    q [S,H,hd]; pool_k/v [n_blocks,KV,bs,hd]; block_tables [S,max_blocks]
     (-1 = unmapped); lengths [S] valid-token counts -> [S,H,hd].
 
     TPU: the Pallas kernel gathers K/V through the block table inside the

@@ -7,18 +7,21 @@ kernel keeps the decode kernel's scalar-prefetch block-table walk (the
 k/v ``index_map`` selects the physical pool block per (segment,
 key-block) grid cell, so only ``block_size`` rows of K/V stream through
 VMEM at a time and no dense per-slot view is ever built) but carries the
-whole chunk of queries ``[C, hd]`` through the sweep with a per-row
-online-softmax accumulator.
+whole chunk of queries through the sweep with a per-row online-softmax
+accumulator.
 
-Grid: ``(n_heads, n_seqs, max_blocks_per_seq)``. For a fixed head the
-(s, j) sweep visits every segment's mapped blocks; each row accumulates
-only blocks of its own segment at key positions at or before its own
-(``seg_ids[i] == s and kpos <= q_pos[i]``) — causal within the chunk,
-isolated across packed prompts. Segments with no resident keys (idle
-slots) are skipped via the prefetched per-segment key counts. Padding
-rows (``seg_ids[i] < 0``) never match a segment, so their accumulator
-stays empty and they emit zeros. GQA is handled in the index_map (head h
-reads kv-head ``h // G``).
+Grid: ``(n_kv_heads, n_seqs, max_blocks_per_seq)``. GQA rides in the
+query block: the wrapper lays the chunk out as ``[KV, C * G, hd]`` (row
+``c * G + g`` is query head ``g`` of the group at chunk row ``c``), so a
+K/V tile of the ``[n_blocks, KV, bs, hd]`` pool is read once per group.
+For a fixed kv head the (s, j) sweep visits every segment's mapped
+blocks; each row accumulates only blocks of its own segment at key
+positions at or before its own (``seg_ids[i] == s and kpos <=
+q_pos[i]``) — causal within the chunk, isolated across packed prompts.
+Segments with no resident keys (idle slots) are skipped via the
+prefetched per-segment key counts. Padding rows (``seg_ids[i] < 0``)
+never match a segment, so their accumulator stays empty and they emit
+zeros.
 """
 from __future__ import annotations
 
@@ -50,28 +53,30 @@ def _kernel(tables_ref, kv_lens_ref, seg_ref, pos_ref, q_ref, k_ref, v_ref,
     # masked territory)
     @pl.when(j * bs < kv_lens_ref[s_i])
     def _accumulate():
-        q = q_ref[...].astype(jnp.float32)       # [C, hd]
-        k = k_ref[...].astype(jnp.float32)       # [bs, hd]
-        v = v_ref[...].astype(jnp.float32)       # [bs, hd]
-        seg = seg_ref[...]                       # [C, 1]
-        pos = pos_ref[...]                       # [C, 1]
+        q = q_ref[...]                           # [R, hd]
+        k = k_ref[...]                           # [bs, hd]
+        v = v_ref[...]                           # [bs, hd]
+        seg = seg_ref[...]                       # [R, 1]
+        pos = pos_ref[...]                       # [R, 1]
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [R, bs]
+        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # own segment only, causally up to and including the row's own
         # position (its K/V is written to the pool before attention)
         mask = (seg == s_i) & (kpos <= pos)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev, l_prev = m_ref[...], l_ref[...]  # [C, 1]
+        m_prev, l_prev = m_ref[...], l_ref[...]  # [R, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         # the mask factor kills rows whose running max is still NEG_INF
         # (padding / no keys yet): there exp(s - m_new) == exp(0) == 1
-        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)  # [C, bs]
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)  # [R, bs]
         l_ref[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
         acc[...] = acc[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when((s_i == n_seg - 1) & (j == n_b - 1))
@@ -87,47 +92,45 @@ def paged_prefill_attention_pallas(q: jax.Array, pool_k: jax.Array,
                                    block_tables: jax.Array,
                                    seg_ids: jax.Array, q_pos: jax.Array,
                                    kv_lens: jax.Array, *,
-                                   interpret: bool = True) -> jax.Array:
-    """q [C,H,hd]; pool_k/v [n_blocks,bs,KV,hd] (one layer's pool);
+                                   interpret: bool = False) -> jax.Array:
+    """q [C,H,hd]; pool_k/v [n_blocks,KV,bs,hd] (one layer's pool);
     block_tables [S,max_blocks] int32 (-1 = unmapped); seg_ids [C] slot
     per row (-1 = padding); q_pos [C] absolute positions; kv_lens [S]
     per-segment resident-token counts (block-skip) -> [C,H,hd]."""
     C, H, hd = q.shape
-    bs = pool_k.shape[1]
-    KV = pool_k.shape[2]
+    KV, bs = pool_k.shape[1], pool_k.shape[2]
     S, mb = block_tables.shape
     G = H // KV
+    R = C * G
     tables = jnp.maximum(block_tables, 0).astype(jnp.int32)
     kernel = functools.partial(_kernel, bs=bs, n_seg=S, n_b=mb,
                                scale=hd ** -0.5)
+    # [C, H, hd] -> [KV, C*G, hd]: one kv group's queries per grid row
+    qg = q.reshape(C, KV, G, hd).transpose(1, 0, 2, 3).reshape(KV, R, hd)
+    rows = lambda x: jnp.repeat(x.astype(jnp.int32), G)[:, None]  # noqa
+    row_spec = pl.BlockSpec((R, 1), lambda g, s, j, tbl, ln: (0, 0))
+    q_spec = pl.BlockSpec((None, R, hd), lambda g, s, j, tbl, ln: (g, 0, 0))
+    # the paged gather: physical block straight from the table
+    kv_spec = pl.BlockSpec((None, None, bs, hd),
+                           lambda g, s, j, tbl, ln: (tbl[s, j], g, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(H, S, mb),
-        in_specs=[
-            pl.BlockSpec((C, 1), lambda h, s, j, tbl, ln: (0, 0)),
-            pl.BlockSpec((C, 1), lambda h, s, j, tbl, ln: (0, 0)),
-            pl.BlockSpec((C, None, hd), lambda h, s, j, tbl, ln: (0, h, 0)),
-            # the paged gather: physical block straight from the table
-            pl.BlockSpec((None, bs, None, hd),
-                         lambda h, s, j, tbl, ln, G=G: (tbl[s, j], 0,
-                                                        h // G, 0)),
-            pl.BlockSpec((None, bs, None, hd),
-                         lambda h, s, j, tbl, ln, G=G: (tbl[s, j], 0,
-                                                        h // G, 0)),
-        ],
-        out_specs=pl.BlockSpec((C, None, hd),
-                               lambda h, s, j, tbl, ln: (0, h, 0)),
+        grid=(KV, S, mb),
+        in_specs=[row_spec, row_spec, q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((C, hd), jnp.float32),
-            pltpu.VMEM((C, 1), jnp.float32),
-            pltpu.VMEM((C, 1), jnp.float32),
+            pltpu.VMEM((R, hd), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((C, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((KV, R, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tables, kv_lens.astype(jnp.int32),
-      seg_ids.astype(jnp.int32)[:, None], q_pos.astype(jnp.int32)[:, None],
-      q, pool_k, pool_v)
+    )(tables, kv_lens.astype(jnp.int32), rows(seg_ids), rows(q_pos),
+      qg.astype(pool_k.dtype), pool_k, pool_v)
+    return out.reshape(KV, C, G, hd).transpose(1, 0, 2, 3).reshape(C, H, hd)

@@ -14,7 +14,7 @@ def paged_prefill_attention_op(q: jax.Array, pool_k: jax.Array,
                                interpret: bool = False) -> jax.Array:
     """Segment-packed prefill attention over one layer's paged pool.
 
-    q [C,H,hd]; pool_k/v [n_blocks,bs,KV,hd]; block_tables [S,max_blocks]
+    q [C,H,hd]; pool_k/v [n_blocks,KV,bs,hd]; block_tables [S,max_blocks]
     (-1 = unmapped); seg_ids [C] slot per row (-1 = padding); q_pos [C]
     absolute positions; kv_lens [S] resident-token counts -> [C,H,hd].
 

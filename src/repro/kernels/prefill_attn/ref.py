@@ -26,7 +26,7 @@ def paged_prefill_attention_ref(q: jax.Array, pool_k: jax.Array,
                                 pool_v: jax.Array, block_tables: jax.Array,
                                 seg_ids: jax.Array, q_pos: jax.Array
                                 ) -> jax.Array:
-    """q [C,H,hd]; pool_k/v [n_blocks,bs,KV,hd]; block_tables [S,mb]
+    """q [C,H,hd]; pool_k/v [n_blocks,KV,bs,hd]; block_tables [S,mb]
     (-1 = unmapped); seg_ids [C] slot per row (-1 = padding row);
     q_pos [C] absolute position per row -> [C,H,hd] (0 for padding)."""
     row_tables = block_tables[jnp.maximum(seg_ids, 0)]       # [C, mb]

@@ -25,7 +25,10 @@ from repro.configs.registry import get_config, list_archs  # noqa: E402
 from repro.distributed.hlo_analysis import roofline_terms  # noqa: E402
 from repro.distributed.hlo_cost import analyze as hlo_analyze  # noqa: E402
 from repro.distributed.sharding import ShardingEnv, use_sharding  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.mesh import (  # noqa: E402
+    PRODUCTION_DEVICE_KIND,
+    make_production_mesh,
+)
 from repro.launch import steps  # noqa: E402
 from repro.models import model as M  # noqa: E402
 from repro.obs.runlog import RunLogger  # noqa: E402
@@ -128,7 +131,8 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     coll_bytes = hc.collective_bytes
     coll_ops = {k: {"count": int(v["count"]), "bytes": int(v["bytes"])}
                 for k, v in hc.collective_ops.items()}
-    terms = roofline_terms(flops, bytes_accessed, coll_bytes)
+    terms = roofline_terms(flops, bytes_accessed, coll_bytes,
+                           PRODUCTION_DEVICE_KIND)
 
     n_params = cfg.num_params()
     n_active = cfg.num_active_params()

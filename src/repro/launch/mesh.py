@@ -10,6 +10,9 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+# device_kind of the production mesh's chips (TPU v5e), for peak lookups
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """TPU v5e mesh: 16x16 (one pod, 256 chips) or 2x16x16 (two pods)."""

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs.base import RLConfig
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.rollout.engine import RolloutEngine
 
@@ -31,12 +32,14 @@ def main() -> None:
     p.add_argument("--max-new", type=int, default=8)
     p.add_argument("--waves", type=int, default=2)
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if jax.default_backend() == "cpu" and cfg.num_params() > 5e7:
         cfg = get_config(args.arch + "-reduced")
         print(f"(CPU host: serving reduced variant of {args.arch})")
-    cfg = dataclasses.replace(cfg, dtype="float32")
+    if jax.default_backend() == "cpu":
+        cfg = dataclasses.replace(cfg, dtype="float32")
 
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     engine = RolloutEngine(cfg, RLConfig(temperature=0.8),

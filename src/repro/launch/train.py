@@ -1,10 +1,11 @@
 """Production training launcher.
 
-On a TPU slice this builds the production mesh, shards params/opt with the
-logical rules, and drives async A-3PO training with the rollout engine on a
-disjoint pod slice (weight publish = device_put across meshes). On CPU (this
-container) ``--mesh local`` runs the same code path on a local mesh at toy
-scale, and ``--mesh prod``/``prod-multipod`` dry-runs the compiled training
+``--mesh local`` builds a mesh over the devices present, shards params and
+Adam moments with the logical rules, and drives async A-3PO training; the
+rollout engine and the trainer share those devices, and a weight publish
+hands the trainer's arrays to the engine in-process (no separate rollout
+slice exists). On a CPU host it runs at toy scale, and
+``--mesh prod``/``prod-multipod`` dry-runs the compiled training
 engine against the full-scale mesh: params and Adam moments are placed with
 ``ShardingEnv``'s logical-axis rules, the scan-based ``train_step`` is
 lowered + compiled with those in_shardings, and the launcher verifies no
@@ -70,6 +71,7 @@ from repro.distributed.sharding import (  # noqa: E402
     ShardingEnv,
     use_sharding,
 )
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_local_mesh, make_production_mesh  # noqa: E402
 from repro.launch import steps  # noqa: E402
 from repro.models import model as M  # noqa: E402
@@ -246,6 +248,7 @@ def main() -> None:
     if args.algo == "list":
         print_algo_list()
         return
+    enable_compile_cache()
     if args.method:
         import warnings
         warnings.warn("--method is deprecated; use --algo",

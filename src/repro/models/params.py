@@ -13,6 +13,7 @@ the logical-axis metadata GSPMD needs.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -77,7 +78,10 @@ def init_from_specs(specs: SpecTree, key: jax.Array, dtype: Any) -> Any:
         sub = out
         for p in path[:-1]:
             sub = sub.setdefault(p, {})
-        leaf_key = jax.random.fold_in(key, hash("/".join(path)) % (2**31))
+        # crc32, not hash(): str hashes are salted per process, and the
+        # same seed must give the same weights in every process
+        leaf_key = jax.random.fold_in(
+            key, zlib.crc32("/".join(path).encode()) % (2**31))
         sub[path[-1]] = _init_leaf(spec, leaf_key, dtype)
     return out
 

@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs.base import ModelConfig, RLConfig
 from repro.data import tokenizer as tok
 from repro.kernels.decode_attn.ops import paged_decode_attention_op
+from repro.kernels.decode_attn.ref import gather_pages
 from repro.kernels.prefill_attn.ops import paged_prefill_attention_op
 from repro.models import blocks as blk_mod
 from repro.models import model as M
@@ -161,10 +162,8 @@ def _decode_tower(params, cfg: ModelConfig, pool_k, pool_v, block_tables,
     """
     def append_attend(li, q, k, v, kv):
         pool_k, pool_v = kv
-        pool_k = pool_k.at[li, write_block, offset].set(
-            k.astype(pool_k.dtype))
-        pool_v = pool_v.at[li, write_block, offset].set(
-            v.astype(pool_v.dtype))
+        pool_k = pc.write_pages(pool_k, li, write_block, offset, k)
+        pool_v = pc.write_pages(pool_v, li, write_block, offset, v)
         # lens + 1: the just-written token is attended (inclusive mask)
         o = paged_decode_attention_op(q, pool_k[li], pool_v[li],
                                       block_tables, lens + 1)
@@ -188,7 +187,7 @@ def _paged_decode_step(params, cfg: ModelConfig, pool_k, pool_v,
     corrupt pages it doesn't own. Returns (logits [S_max, V], pool_k,
     pool_v).
     """
-    bs = pool_k.shape[2]
+    bs = pool_k.shape[3]
     safe_tables = jnp.maximum(block_tables, 0)
     blk_idx = seq_lens // bs
     write_block = jnp.take_along_axis(safe_tables, blk_idx[:, None],
@@ -214,10 +213,8 @@ def _prefill_tower(params, cfg: ModelConfig, pool_k, pool_v, block_tables,
     """
     def append_attend(li, q, k, v, kv):
         pool_k, pool_v = kv
-        pool_k = pool_k.at[li, write_block, offset].set(
-            k.astype(pool_k.dtype))
-        pool_v = pool_v.at[li, write_block, offset].set(
-            v.astype(pool_v.dtype))
+        pool_k = pc.write_pages(pool_k, li, write_block, offset, k)
+        pool_v = pc.write_pages(pool_v, li, write_block, offset, v)
         o = paged_prefill_attention_op(q, pool_k[li], pool_v[li],
                                        block_tables, seg_ids, q_pos,
                                        kv_lens)
@@ -245,7 +242,7 @@ def _paged_prefill_chunk(params, cfg: ModelConfig, pool_k, pool_v,
     next-token logits installed; ``seq_lens`` advances by the rows
     written. Compiles once per (C bucket, S) shape.
     """
-    bs = pool_k.shape[2]
+    bs = pool_k.shape[3]
     safe_tables = jnp.maximum(block_tables, 0)
     row_tables = safe_tables[jnp.maximum(seg_ids, 0)]        # [C, mb]
     blk_idx = jnp.minimum(q_pos // bs, row_tables.shape[1] - 1)
@@ -276,7 +273,7 @@ def _dense_prefill(params, cfg: ModelConfig, pool_k, pool_v, tokens,
     copies.
     """
     Pb = tokens.shape[1]
-    bs = pool_k.shape[2]
+    bs = pool_k.shape[3]
     hidden, cache = M.prefill(params, cfg, tokens,
                               lengths=length[None], max_len=Pb)
     k = cache["attn"]["k"][:, 0]  # [L, Pb, KV, hd]
@@ -286,8 +283,8 @@ def _dense_prefill(params, cfg: ModelConfig, pool_k, pool_v, tokens,
     phys = jnp.where(pos < length, jnp.maximum(table, 0)[blk_idx],
                      trash_block)
     off = jnp.where(pos < length, pos % bs, 0)
-    pool_k = pool_k.at[:, phys, off].set(k.astype(pool_k.dtype))
-    pool_v = pool_v.at[:, phys, off].set(v.astype(pool_v.dtype))
+    pool_k = pc.write_pages(pool_k, None, phys, off, k)
+    pool_v = pc.write_pages(pool_v, None, phys, off, v)
     h_last = jnp.take(hidden[0], length - 1, axis=0)
     logits = logits_from_hidden(params["embedding"], h_last[None], cfg)[0]
     return logits, pool_k, pool_v
@@ -353,7 +350,7 @@ def _paged_decode_horizon(params, cfg: ModelConfig, pool_k, pool_v,
     drained to host as ONE transfer), plus the updated pool, lengths, and
     next-token logits, which all stay on device.
     """
-    bs = pool_k.shape[2]
+    bs = pool_k.shape[3]
     S, mb = block_tables.shape
     safe_tables = jnp.maximum(block_tables, 0)
     if use_view is None:
@@ -399,11 +396,9 @@ def _paged_decode_horizon(params, cfg: ModelConfig, pool_k, pool_v,
 
     ts = jnp.arange(horizon, dtype=jnp.int32)
     if use_view:
-        n_layers = pool_k.shape[0]
-        view_k = pool_k[:, safe_tables].reshape(
-            n_layers, S, mb * bs, *pool_k.shape[3:])
-        view_v = pool_v[:, safe_tables].reshape(
-            n_layers, S, mb * bs, *pool_v.shape[3:])
+        gather = jax.vmap(gather_pages, in_axes=(0, None))
+        view_k = gather(pool_k, block_tables)
+        view_v = gather(pool_v, block_tables)
         (view_k, view_v, lens, logits, _, _), (tokens, logps, masks) = \
             jax.lax.scan(one_token_view,
                          (view_k, view_v, seq_lens, next_logits, done0,
@@ -420,9 +415,9 @@ def _paged_decode_horizon(params, cfg: ModelConfig, pool_k, pool_v,
         blk = safe_tables[rows[None, :], jnp.minimum(pos // bs, mb - 1)]
         blk = jnp.where(emits, blk, trash_block).reshape(-1)
         off = jnp.where(emits, pos % bs, 0).reshape(-1)
-        flat = (n_layers, horizon * S) + pool_k.shape[3:]
-        pool_k = pool_k.at[:, blk, off].set(new_k.reshape(flat))
-        pool_v = pool_v.at[:, blk, off].set(new_v.reshape(flat))
+        flat = (new_k.shape[0], horizon * S) + new_k.shape[3:]
+        pool_k = pc.write_pages(pool_k, None, blk, off, new_k.reshape(flat))
+        pool_v = pc.write_pages(pool_v, None, blk, off, new_v.reshape(flat))
     else:
         (pool_k, pool_v, lens, logits, _, _), (tokens, logps, masks) = \
             jax.lax.scan(one_token_paged,
@@ -494,7 +489,7 @@ def _multiarch_decode_step(params, cfg: ModelConfig, pool_k, pool_v, conv,
     """SSM/hybrid variant of ``_paged_decode_step``: one token per slot,
     KV appended into the paged pool (hybrid attention layers) and the
     recurrent state pools advanced, with ``active`` gating both."""
-    bs = pool_k.shape[2]
+    bs = pool_k.shape[3]
     safe_tables = jnp.maximum(block_tables, 0)
     blk_idx = seq_lens // bs
     write_block = jnp.take_along_axis(safe_tables, blk_idx[:, None],
@@ -504,10 +499,8 @@ def _multiarch_decode_step(params, cfg: ModelConfig, pool_k, pool_v, conv,
 
     def append_attend(li, q, k, v, kv):
         pool_k, pool_v = kv
-        pool_k = pool_k.at[li, write_block, offset].set(
-            k.astype(pool_k.dtype))
-        pool_v = pool_v.at[li, write_block, offset].set(
-            v.astype(pool_v.dtype))
+        pool_k = pc.write_pages(pool_k, li, write_block, offset, k)
+        pool_v = pc.write_pages(pool_v, li, write_block, offset, v)
         o = paged_decode_attention_op(q, pool_k[li], pool_v[li],
                                       block_tables, seq_lens + 1)
         return o, (pool_k, pool_v)
@@ -538,7 +531,7 @@ def _multiarch_decode_horizon(params, cfg: ModelConfig, pool_k, pool_v,
     already O(1) per slot, and the hybrid attention layers take the paged
     path on every backend.
     """
-    bs = pool_k.shape[2]
+    bs = pool_k.shape[3]
     safe_tables = jnp.maximum(block_tables, 0)
     done0 = budget <= 0
 
@@ -563,8 +556,8 @@ def _multiarch_decode_horizon(params, cfg: ModelConfig, pool_k, pool_v,
 
         def append_attend(li, q, k, v, kv):
             pool_k, pool_v = kv
-            pool_k = pool_k.at[li, wb, off].set(k.astype(pool_k.dtype))
-            pool_v = pool_v.at[li, wb, off].set(v.astype(pool_v.dtype))
+            pool_k = pc.write_pages(pool_k, li, wb, off, k)
+            pool_v = pc.write_pages(pool_v, li, wb, off, v)
             o = paged_decode_attention_op(q, pool_k[li], pool_v[li],
                                           block_tables, lens + 1)
             return o, (pool_k, pool_v)
@@ -607,7 +600,7 @@ def _multiarch_prefill_chunk(params, cfg: ModelConfig, pool_k, pool_v,
     get next-token logits installed; ``seq_lens`` advances by ``counts``.
     """
     S, Cb = tokens.shape
-    bs = pool_k.shape[2]
+    bs = pool_k.shape[3]
     row_active = counts > 0
     pad_mask = jnp.arange(Cb)[None, :] < counts[:, None]           # [S, Cb]
     positions = starts[:, None] + jnp.arange(Cb, dtype=jnp.int32)  # [S, Cb]
@@ -656,10 +649,8 @@ def _multiarch_prefill_chunk(params, cfg: ModelConfig, pool_k, pool_v,
             def flat(t):
                 return t.reshape((S * Cb,) + t.shape[2:])
 
-            pool_k = pool_k.at[ai, wb, off].set(
-                flat(k).astype(pool_k.dtype))
-            pool_v = pool_v.at[ai, wb, off].set(
-                flat(v).astype(pool_v.dtype))
+            pool_k = pc.write_pages(pool_k, ai, wb, off, flat(k))
+            pool_v = pc.write_pages(pool_v, ai, wb, off, flat(v))
             o = paged_prefill_attention_op(flat(q), pool_k[ai], pool_v[ai],
                                            block_tables, seg_flat,
                                            pos_flat, kv_lens)

@@ -9,7 +9,9 @@ contiguous DMA-friendly reads, and freed blocks are recycled by index
 bookkeeping on the host.
 
 Layout:
-  pool_k/pool_v : [n_layers, n_blocks, block_size, KV, hd]
+  pool_k/pool_v : [n_layers, n_blocks, KV, block_size, hd] — one (block,
+                  kv-head) page is a contiguous [block_size, hd] tile, the
+                  block shape the paged Pallas kernels stream through VMEM
   block_tables  : [max_seqs, max_blocks_per_seq] int32 (-1 = unmapped)
   seq_lens      : [max_seqs] int32
 """
@@ -23,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.decode_attn.ref import gather_pages
 
 
 @dataclasses.dataclass
@@ -34,7 +37,7 @@ class PagedCacheState:
 
     @property
     def block_size(self) -> int:
-        return self.pool_k.shape[2]
+        return self.pool_k.shape[3]
 
     @property
     def max_blocks(self) -> int:
@@ -105,7 +108,7 @@ def init_paged_cache(cfg: ModelConfig, *, n_blocks: int, block_size: int,
     # bookkeeping stays uniform across architectures at zero memory cost.
     n_attn = sum(1 for k in cfg.block_kinds() if k == "attn")
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    shape = (n_attn, n_blocks, block_size, kv, hd)
+    shape = (n_attn, n_blocks, kv, block_size, hd)
     return PagedCacheState(
         pool_k=jnp.zeros(shape, dtype),
         pool_v=jnp.zeros(shape, dtype),
@@ -209,6 +212,19 @@ class SSMSlotPool:
 
 
 # ------------------------------------------------------------------ device ops
+def write_pages(pool: jax.Array, layer: Optional[int], blocks: jax.Array,
+                offsets: jax.Array, x: jax.Array) -> jax.Array:
+    """Scatter per-token K or V into the pool at (block, offset) pairs.
+
+    ``x`` is [N, KV, hd] for one ``layer``, or [L, N, KV, hd] for every
+    layer at once when ``layer`` is None.
+    """
+    if layer is None:
+        x = jnp.moveaxis(x, 0, 1)  # the advanced (block, offset) axis leads
+        return pool.at[:, blocks, :, offsets].set(x.astype(pool.dtype))
+    return pool.at[layer, blocks, :, offsets].set(x.astype(pool.dtype))
+
+
 def write_token(state: PagedCacheState, layer: int, k: jax.Array,
                 v: jax.Array, slot_ids: jax.Array) -> PagedCacheState:
     """Write one token's K/V for active slots.
@@ -229,10 +245,8 @@ def write_token(state: PagedCacheState, layer: int, k: jax.Array,
     blocks = jnp.where(unmapped, state.pool_k.shape[1] - 1, blocks)
     offset = jnp.where(unmapped, 0, offset)
 
-    pool_k = state.pool_k.at[layer, blocks, offset].set(
-        k.astype(state.pool_k.dtype))
-    pool_v = state.pool_v.at[layer, blocks, offset].set(
-        v.astype(state.pool_v.dtype))
+    pool_k = write_pages(state.pool_k, layer, blocks, offset, k)
+    pool_v = write_pages(state.pool_v, layer, blocks, offset, v)
     return dataclasses.replace(state, pool_k=pool_k, pool_v=pool_v)
 
 
@@ -244,16 +258,11 @@ def gather_kv(state: PagedCacheState, layer: int, slot_ids: jax.Array
     gather over the block pool (one XLA gather per layer), letting the
     regular decode attention run on the result.
     """
-    bs = state.block_size
     tables = state.block_tables[slot_ids]            # [B, max_blocks]
-    safe = jnp.maximum(tables, 0)
-    k = state.pool_k[layer][safe]                    # [B, mb, bs, KV, hd]
-    v = state.pool_v[layer][safe]
-    B, mb = tables.shape
-    k = k.reshape(B, mb * bs, *k.shape[3:])
-    v = v.reshape(B, mb * bs, *v.shape[3:])
+    k = gather_pages(state.pool_k[layer], tables)    # [B, mb*bs, KV, hd]
+    v = gather_pages(state.pool_v[layer], tables)
     lens = state.seq_lens[slot_ids]
-    valid = jnp.arange(mb * bs)[None, :] < lens[:, None]
+    valid = jnp.arange(k.shape[1])[None, :] < lens[:, None]
     # tokens in unmapped blocks are never valid (len bound covers them)
     return k, v, valid
 

@@ -359,20 +359,24 @@ class Trainer:
 
     def init_state(self, key, dtype=None) -> TrainState:
         """Initialize params + Adam moments, placed with the active
-        ``ShardingEnv``'s logical-axis rules when one is installed."""
-        params = M.init_params(self.cfg, key, dtype=dtype)
-        opt = adam_init(params)
+        ``ShardingEnv``'s logical-axis rules when one is installed.
+
+        Under a mesh the initializer is one jitted program whose
+        ``out_shardings`` are those placements, so each device only ever
+        materializes its own shard of the state."""
+        def init(key):
+            params = M.init_params(self.cfg, key, dtype=dtype)
+            return params, adam_init(params)
+
         env = current_env()
-        if env is not None:
+        if env is None:
+            params, opt = init(key)
+        else:
             from jax.sharding import NamedSharding, PartitionSpec
             psh = M.param_shardings(self.cfg, env)
-            params = jax.device_put(params, psh)
-            opt = {
-                "m": jax.device_put(opt["m"], psh),
-                "v": jax.device_put(opt["v"], psh),
-                "t": jax.device_put(opt["t"],
-                                    NamedSharding(env.mesh, PartitionSpec())),
-            }
+            rep = NamedSharding(env.mesh, PartitionSpec())
+            params, opt = jax.jit(init, out_shardings=(
+                psh, {"m": psh, "v": psh, "t": rep}))(key)
         return TrainState(params, opt, jnp.zeros((), jnp.int32))
 
     def step(self, state: TrainState, batch: TrainBatch

@@ -254,9 +254,10 @@ def test_write_token_routes_unmapped_to_scratch():
     k = jnp.ones((2, kv, hd), jnp.float32)
     out = pc.write_token(state, 0, k, 2 * k, jnp.asarray([0, 1]))
     pool_k = np.asarray(out.pool_k)
-    assert pool_k[0, 0, 0].any()          # slot 0's legit write
-    assert pool_k[0, 3, 0].any()          # unmapped write -> scratch
-    assert not pool_k[0, 0, 1].any()      # block 0 slot-1 offset untouched
+    # pool_k is [layer, block, kv, offset, hd]
+    assert pool_k[0, 0, :, 0].any()       # slot 0's legit write
+    assert pool_k[0, 3, :, 0].any()       # unmapped write -> scratch
+    assert not pool_k[0, 0, :, 1].any()   # block 0 slot-1 offset untouched
     assert not pool_k[0, 1].any() and not pool_k[0, 2].any()
 
 

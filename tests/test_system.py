@@ -187,11 +187,23 @@ def test_rollout_queue_staleness_gate():
     assert q.dropped == 1
 
 
+def test_roofline_peaks_keyed_by_device_kind():
+    """v5e's published peaks drive the roofline; a device with no table
+    entry is an error, never silently costed as a v5e."""
+    from repro.distributed.hlo_analysis import roofline_terms
+    t = roofline_terms(197e12, 819e9 * 2, 0.0, "TPU v5 lite")
+    assert t["compute_s"] == pytest.approx(1.0)
+    assert t["memory_s"] == pytest.approx(2.0)
+    assert t["dominant"] == "memory_s"
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline_terms(1.0, 1.0, 1.0, "TPU v4")
+
+
 def test_sharding_env_divisibility_fallback():
     """kv_heads=8 on model=16 must fall back to replication, not crash."""
-    from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import ShardingEnv, abstract_mesh
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+    from repro.distributed.sharding import ShardingEnv
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     env = ShardingEnv(mesh)
     # kv=8 not divisible by model=16 -> replicated
     assert env.spec((8, 128), ("kv_heads", "head_dim")) == P()
@@ -203,7 +215,7 @@ def test_sharding_env_divisibility_fallback():
     env2 = ShardingEnv(mesh, fsdp=False)
     assert env2.spec((4096, 11008), ("embed", "ff")) == P(None, "model")
     # batch spans (pod, data) on the multi-pod mesh
-    mesh3 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     env3 = ShardingEnv(mesh3)
     assert env3.spec((256, 4096), ("batch", "seq")) == P(("pod", "data"))
     # batch=1 (long_500k) -> replicated
