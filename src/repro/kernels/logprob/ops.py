@@ -47,8 +47,11 @@ def _kernel_fwd(hidden, w, targets, interpret):
 def _kernel_bwd(interpret, res, g):
     hidden, w, targets, logz, ent = res
     g_logp, g_ent = g
-    dh, dw = token_logprob_entropy_bwd(hidden, w, targets, logz, ent,
-                                       g_logp, g_ent)
+    # its own scope: the name stack under a custom VJP's backward does not
+    # reliably carry the caller's scopes
+    with jax.named_scope("logprob_head_bwd"):
+        dh, dw = token_logprob_entropy_bwd(hidden, w, targets, logz, ent,
+                                           g_logp, g_ent)
     return dh, dw, None
 
 

@@ -127,7 +127,9 @@ def forward_hidden(params, cfg: ModelConfig, tokens: jax.Array,
     aux0 = jnp.zeros((), jnp.float32)
 
     if cfg.arch_type == "hybrid":
-        x, aux = _hybrid_full(params, cfg, x, positions, pad_mask, window)
+        with jax.named_scope("decoder"):
+            x, aux = _hybrid_full(params, cfg, x, positions, pad_mask,
+                                  window)
     elif cfg.arch_type == "ssm":
         def body(carry, layer_params):
             h, aux = carry
@@ -135,7 +137,8 @@ def forward_hidden(params, cfg: ModelConfig, tokens: jax.Array,
             return (h, aux + a), None
         if cfg.remat:
             body = jax.checkpoint(body)
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
+        with jax.named_scope("decoder"):
+            (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
     else:
         def body(carry, layer_params):
             h, aux = carry
@@ -151,7 +154,8 @@ def forward_hidden(params, cfg: ModelConfig, tokens: jax.Array,
             return (h, aux + a), None
         if cfg.remat:
             body = jax.checkpoint(body)
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
+        with jax.named_scope("decoder"):
+            (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return x, aux

@@ -3,9 +3,10 @@
 Three pillars, one import surface:
 
 * ``obs.tracing`` — a low-overhead span tracer (``span(...)`` context
-  manager / ``trace_span`` decorator, thread-aware, monotonic clocks)
-  exporting Chrome/Perfetto ``trace.json``, with flow events tying a
-  weight publish to the serving step that resumed under it.
+  manager, thread-aware, monotonic clocks) exporting Chrome/Perfetto
+  ``trace.json``, with flow events tying a weight publish to the serving
+  step that resumed under it; installed with ``annotate_jax=True`` its
+  spans also land in a captured ``jax.profiler`` device profile.
 * ``obs.metrics`` — a process-wide metrics registry (Counter / Gauge /
   Histogram with labels); ``serving.metrics.ServingMetrics`` is a thin
   facade over it and training-side metrics land in the same registry, so
@@ -32,13 +33,10 @@ from repro.obs.runlog import (
 )
 from repro.obs.tracing import (
     SpanTracer,
-    annotate,
     flow_end,
     flow_start,
-    get_tracer,
     install_tracer,
     span,
-    trace_span,
 )
 
 __all__ = [
@@ -50,13 +48,10 @@ __all__ = [
     "RunLogger",
     "STEP_REQUIRED_KEYS",
     "SpanTracer",
-    "annotate",
     "flow_end",
     "flow_start",
     "get_registry",
-    "get_tracer",
     "install_tracer",
     "span",
     "step_record_dict",
-    "trace_span",
 ]

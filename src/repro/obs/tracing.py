@@ -12,17 +12,16 @@ Design constraints (the async loop is the hot path being measured):
   interleaving A-3PO exploits is visually inspectable.
 * **Monotonic clocks.** ``time.perf_counter_ns`` relative to tracer
   install; timestamps are microseconds as the trace-event format wants.
+* **One clock with the device.** Installed with ``annotate_jax=True``,
+  every span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+  a device profile captured meanwhile carries the program's spans on its
+  host plane, on the same clock as the device ops.
 * **Causality.** ``flow_start``/``flow_end`` emit Chrome flow events
   (``ph: s/f``) that arrows a weight publish to the serving/rollout span
   that first ran under the published version.
 
 Spans carry arbitrary key=value attributes (``args`` in the trace event),
 e.g. per-span staleness, token counts, weight versions.
-
-``annotate(name)`` additionally brackets a region with
-``jax.profiler.TraceAnnotation`` so device profiles (``jax.profiler``)
-line up with host spans — enabled together with the tracer, a no-op
-otherwise.
 """
 from __future__ import annotations
 
@@ -52,24 +51,13 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
-class _NoopAnnotation:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_ANNOTATION = _NoopAnnotation()
-
-
 # ------------------------------------------------------------------- tracer
 class _Span:
-    """A live span; records a complete ("X") event on exit."""
+    """A live span; records a complete ("X") event on exit, and brackets
+    itself with a profiler annotation when the tracer was installed with
+    ``annotate_jax=True``."""
 
-    __slots__ = ("_tracer", "name", "_start_ns", "attrs")
+    __slots__ = ("_tracer", "name", "_start_ns", "attrs", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  attrs: Optional[Dict[str, Any]]):
@@ -77,6 +65,7 @@ class _Span:
         self.name = name
         self.attrs = attrs
         self._start_ns = 0
+        self._ann = None
 
     def set(self, **attrs) -> None:
         """Attach attributes discovered mid-span (token counts etc.)."""
@@ -86,12 +75,18 @@ class _Span:
             self.attrs.update(attrs)
 
     def __enter__(self):
+        if _ANNOTATION is not None:
+            self._ann = _ANNOTATION(self.name)
+            self._ann.__enter__()
         self._start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         end = time.perf_counter_ns()
         self._tracer._complete(self.name, self._start_ns, end, self.attrs)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         return False
 
 
@@ -218,25 +213,25 @@ def _jsonable(v):
 
 # ----------------------------------------------------------- module controls
 _TRACER: Optional[SpanTracer] = None
-_ANNOTATE = False
+# ``jax.profiler.TraceAnnotation`` while spans are to be annotated, else None
+_ANNOTATION = None
 
 
 def install_tracer(tracer: Optional[SpanTracer] = None, *,
                    annotate_jax: bool = False) -> Optional[SpanTracer]:
     """Install (or, with ``None``, remove) the process-wide tracer.
 
-    ``annotate_jax=True`` additionally brackets ``annotate(...)`` regions
-    with ``jax.profiler.TraceAnnotation`` so a concurrently captured
-    device profile carries the same region names.
+    ``annotate_jax=True`` additionally brackets every span with a
+    ``jax.profiler.TraceAnnotation`` of its name, so a concurrently
+    captured device profile carries the spans on the device's clock.
     """
-    global _TRACER, _ANNOTATE
+    global _TRACER, _ANNOTATION
     _TRACER = tracer
-    _ANNOTATE = bool(annotate_jax) and tracer is not None
+    _ANNOTATION = None
+    if annotate_jax and tracer is not None:
+        import jax
+        _ANNOTATION = jax.profiler.TraceAnnotation
     return tracer
-
-
-def get_tracer() -> Optional[SpanTracer]:
-    return _TRACER
 
 
 def span(name: str, **attrs):
@@ -271,40 +266,13 @@ def flow_end(name: str, flow_id: int, **attrs) -> None:
         t.flow_end(name, flow_id, **attrs)
 
 
-def annotate(name: str):
-    """``jax.profiler.TraceAnnotation`` bracket, active only when the
-    tracer was installed with ``annotate_jax=True`` (profiling on)."""
-    if not _ANNOTATE:
-        return _NOOP_ANNOTATION
-    import jax
-    return jax.profiler.TraceAnnotation(name)
-
-
 def step_annotation(step: int):
     """``jax.profiler.StepTraceAnnotation`` for the outer training step —
     groups device activity per step in a captured profile."""
-    if not _ANNOTATE:
-        return _NOOP_ANNOTATION
+    if _ANNOTATION is None:
+        return _NOOP
     import jax
     return jax.profiler.StepTraceAnnotation("train", step_num=step)
-
-
-def trace_span(name: Optional[str] = None, **attrs):
-    """Decorator form of ``span`` (span name defaults to the function's
-    qualified name)."""
-    def deco(fn):
-        import functools
-        sp_name = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapped(*a, **kw):
-            t = _TRACER
-            if t is None:
-                return fn(*a, **kw)
-            with t.span(sp_name, **attrs):
-                return fn(*a, **kw)
-        return wrapped
-    return deco
 
 
 # ----------------------------------------------------- phase classification

@@ -35,7 +35,7 @@ from repro.models.layers import (
     rmsnorm,
 )
 from repro.models.layers import swiglu
-from repro.obs.tracing import annotate, span
+from repro.obs.tracing import span
 from repro.rollout import paged_cache as pc
 from repro.rollout.sampler import (
     fused_sample_step,
@@ -1350,7 +1350,7 @@ class ContinuousBatchingEngine:
         for s, r in active.items():
             budget[s] = min(H, r.max_new - len(r.generated))
         self._prepare_decode({s: int(budget[s]) for s in active})
-        with annotate("decode_horizon"):
+        with span("decode_dispatch"):
             if self.n_ssm:
                 (packed, pool_k, pool_v, conv, state, lens, logits) = \
                     _multiarch_decode_horizon(

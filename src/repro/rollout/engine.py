@@ -19,7 +19,7 @@ from repro.configs.base import ModelConfig, RLConfig
 from repro.data import tokenizer as tok
 from repro.models import model as M
 from repro.models.layers import logits_from_hidden
-from repro.obs.tracing import annotate, span
+from repro.obs.tracing import span
 from repro.rollout.sampler import fused_sample_step
 
 
@@ -92,8 +92,7 @@ class RolloutEngine:
                  prompt_lengths: np.ndarray, key, *, version: int = 0,
                  greedy: bool = False) -> RolloutBatch:
         with span("rollout_generate", batch=int(prompts.shape[0]),
-                  max_new=self.max_new_tokens, version=version), \
-                annotate("rollout_generate"):
+                  max_new=self.max_new_tokens, version=version):
             toks, logps, masks = _generate_jit(
                 params, self.cfg, jnp.asarray(prompts),
                 jnp.asarray(prompt_lengths), key, self.max_new_tokens,

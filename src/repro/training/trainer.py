@@ -43,7 +43,7 @@ from repro.kernels.logprob import token_logprob_entropy
 from repro.models import model as M
 from repro.models.layers import output_head_weight
 from repro.obs.metrics import get_registry
-from repro.obs.tracing import annotate, span
+from repro.obs.tracing import span
 from repro.rollout.engine import RolloutBatch
 from repro.training.optimizer import adam_init, adam_update
 
@@ -124,7 +124,8 @@ def _score_tokens(params, cfg: ModelConfig, tokens: jax.Array
     tokens = constrain(tokens, "batch", None)
     hidden, aux = M.forward_hidden(params, cfg, tokens[:, :-1])
     w = output_head_weight(params["embedding"], cfg)
-    logp, entropy = token_logprob_entropy(hidden, w, tokens[:, 1:])
+    with jax.named_scope("logprob_head"):
+        logp, entropy = token_logprob_entropy(hidden, w, tokens[:, 1:])
     return (constrain(logp, "batch", None), constrain(entropy, "batch", None),
             aux)
 
@@ -234,11 +235,12 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
     def loss_fn(p, t):
         t = _constrain_batch(t)
         logp, entropy, aux = _score_tokens(p, cfg, t["tokens"])
-        loss, metrics = algo.loss(logp, LossInputs(
-            advantages=t["advantages"], mask=t["mask"],
-            behav_logp=t.get("behav_logp"), versions=t.get("versions"),
-            current_version=version, prox_logp=t.get("prox"),
-            entropy=entropy), rl)
+        with jax.named_scope("policy_loss"):
+            loss, metrics = algo.loss(logp, LossInputs(
+                advantages=t["advantages"], mask=t["mask"],
+                behav_logp=t.get("behav_logp"), versions=t.get("versions"),
+                current_version=version, prox_logp=t.get("prox"),
+                entropy=entropy), rl)
         return loss + aux, metrics
 
     def grads_of(p, t):
@@ -271,7 +273,8 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
     def minibatch_body(carry, t):
         p, o = carry
         (loss, metrics), grads = grads_of(p, t)
-        p2, o2, gnorm = adam_update(grads, o, p, rl)
+        with jax.named_scope("optimizer"):
+            p2, o2, gnorm = adam_update(grads, o, p, rl)
         # on-device non-finite guard: grad_norm is a global reduction, so
         # any NaN/Inf gradient leaf poisons it — one scalar flag covers
         # loss + every gradient, and it rides the packed metric array
@@ -398,35 +401,38 @@ class Trainer:
         t0 = time.perf_counter()
         prox = None
         if self.algo.needs_prox_forward:
-            with span("prox_forward", algo=self.algo.name), \
-                    annotate("prox_forward"):
+            with span("prox_forward", algo=self.algo.name):
                 prox = recompute_prox_logp(state.params, self.cfg,
                                            batch.tokens)
                 prox.block_until_ready()
             host_syncs += 1
         prox_time = time.perf_counter() - t0
 
+        # train_dispatch returns once the step is enqueued; train_fetch is
+        # the host waiting on the device; train_metrics is host bookkeeping
         with span("train_update", algo=self.algo.name,
-                  batch=int(B), minibatches=int(nmb)), \
-                annotate("train_update"):
+                  batch=int(B), minibatches=int(nmb)):
             step_fn = (_train_step_donating if self.donate_params
                        else _train_step)
-            params, opt, packed = step_fn(
-                state.params, state.opt, state.version, batch.tokens,
-                batch.behav_logp, batch.response_mask, batch.versions,
-                batch.rewards, prox, cfg=self.cfg, rl=rl, algo=self.algo,
-                num_minibatches=nmb,
-                num_microbatches=self.num_microbatches,
-                skip_nonfinite=self.skip_nonfinite)
+            with span("train_dispatch"):
+                params, opt, packed = step_fn(
+                    state.params, state.opt, state.version, batch.tokens,
+                    batch.behav_logp, batch.response_mask, batch.versions,
+                    batch.rewards, prox, cfg=self.cfg, rl=rl,
+                    algo=self.algo, num_minibatches=nmb,
+                    num_microbatches=self.num_microbatches,
+                    skip_nonfinite=self.skip_nonfinite)
 
             # the single device->host transfer of the step
-            values = jax.device_get(packed)
-        host_syncs += 1
-        out = {k: float(v) for k, v in zip(METRIC_KEYS, values)}
-        out["prox_time_s"] = prox_time
-        out["host_syncs"] = float(host_syncs)
-        self.last_host_syncs = host_syncs
-        self._publish_metrics(out)
+            with span("train_fetch"):
+                values = jax.device_get(packed)
+            host_syncs += 1
+            out = {k: float(v) for k, v in zip(METRIC_KEYS, values)}
+            out["prox_time_s"] = prox_time
+            out["host_syncs"] = float(host_syncs)
+            self.last_host_syncs = host_syncs
+            with span("train_metrics"):
+                self._publish_metrics(out)
         new_state = TrainState(params, opt, state.version + 1)
         return new_state, out
 

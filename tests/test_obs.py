@@ -17,8 +17,9 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                get_registry)
 from repro.obs.runlog import (RUNLOG_SCHEMA_VERSION, STEP_REQUIRED_KEYS,
                               RunLogger, read_jsonl)
+from repro.obs import tracing
 from repro.obs.tracing import (PHASE_SPANS, SpanTracer, install_tracer,
-                               phase_breakdown, span, trace_span)
+                               phase_breakdown, span)
 
 
 @pytest.fixture
@@ -195,13 +196,84 @@ class TestTracer:
         from repro.obs.validate import validate_trace
         assert validate_trace(path, expect_spans=["a"]) == []
 
-    def test_trace_span_decorator(self, tracer):
-        @trace_span("decorated")
-        def f(x):
-            return x + 1
-        assert f(1) == 2
-        assert any(e["name"] == "decorated" for e in tracer.events()
-                   if e["ph"] == "X")
+    def test_span_lands_on_the_profiler_host_plane(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        install_tracer(SpanTracer(), annotate_jax=True)
+        try:
+            jax.profiler.start_trace(str(tmp_path))
+            with span("x"):
+                with span("x_child"):
+                    jax.numpy.ones(4).block_until_ready()
+            jax.profiler.stop_trace()
+        finally:
+            install_tracer(None)
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        host = {e.name: e for p in ProfileData.from_file(path).planes
+                if p.name.startswith("/host:") for ln in p.lines
+                for e in ln.events}
+        assert {"x", "x_child"} <= set(host)
+        x, kid = host["x"], host["x_child"]
+        assert x.start_ns <= kid.start_ns
+        assert kid.start_ns + kid.duration_ns <= x.start_ns + x.duration_ns
+
+    def test_no_tracer_returns_the_shared_noop_and_no_annotation(
+            self, monkeypatch):
+        import jax
+
+        opened = []
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            lambda name: opened.append(name))
+        install_tracer(None)
+        sp = span("x", k=1)
+        assert sp is tracing._NOOP
+        with sp:
+            pass
+        # a tracer without annotate_jax records the span, annotates nothing
+        t = install_tracer(SpanTracer())
+        try:
+            with span("y"):
+                pass
+        finally:
+            install_tracer(None)
+        assert opened == []
+        assert [e["name"] for e in t.events() if e["ph"] == "X"] == ["y"]
+
+    def test_train_step_children_nest_in_train_update(self, tracer):
+        import dataclasses
+
+        import jax
+        import jax.numpy as jnp
+
+        from repro.configs.base import RLConfig
+        from repro.configs.registry import get_config
+        from repro.training.trainer import TrainBatch, Trainer
+
+        cfg = dataclasses.replace(get_config("toy-2m"), dtype="float32")
+        trainer = Trainer(cfg, RLConfig(group_size=2, num_minibatches=2),
+                          "a3po")
+        state = trainer.init_state(jax.random.PRNGKey(0))
+        B, T = 4, 8
+        mask = jnp.ones((B, T - 1), jnp.float32)
+        batch = TrainBatch(tokens=jnp.full((B, T), 5, jnp.int32),
+                           response_mask=mask, behav_logp=-mask,
+                           versions=jnp.zeros((B,), jnp.int32),
+                           rewards=jnp.arange(B, dtype=jnp.float32))
+        trainer.step(state, batch)
+        ev = {e["name"]: e for e in tracer.events() if e["ph"] == "X"}
+        outer = ev["train_update"]
+        kids = [ev[n] for n in ("train_dispatch", "train_fetch",
+                                "train_metrics")]
+        for k in kids:
+            assert outer["ts"] <= k["ts"]
+            assert k["ts"] + k["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+        # in order, each starting after the one before it ends
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-3
 
     def test_phase_breakdown_counts_only_leaf_spans(self, tracer):
         with span("train_step", step=0):     # wrapper: not a phase span
