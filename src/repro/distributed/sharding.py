@@ -82,7 +82,11 @@ class ShardingEnv:
         parts = []
         for dim, name in zip(shape, logical_axes):
             axes = self._mesh_axes_for(name)
-            axes = tuple(a for a in axes if a not in used)
+            # an axis of one device shards nothing: leaving it unnamed
+            # keeps equivalent placements equal, so a jitted step whose
+            # outputs come back replicated reuses its program
+            axes = tuple(a for a in axes
+                         if a not in used and self.mesh.shape[a] > 1)
             size = int(np.prod([self.mesh.shape[a] for a in axes])) if axes else 1
             if axes and dim % size == 0 and dim >= size:
                 used.update(axes)
@@ -90,7 +94,7 @@ class ShardingEnv:
             else:
                 parts.append(None)
         if (self.tp_fallback and "model" in self.mesh.axis_names
-                and "model" not in used):
+                and "model" not in used and self.mesh.shape["model"] > 1):
             msize = self.mesh.shape["model"]
             for i, (dim, name) in enumerate(zip(shape, logical_axes)):
                 if (name == "embed" and parts[i] is None
@@ -122,6 +126,16 @@ def use_sharding(env: Optional[ShardingEnv]):
         yield env
     finally:
         _LOCAL.env = prev
+
+
+def batch_shard_count() -> int:
+    """Devices the "batch" logical axis splits over in the active mesh
+    (1 outside a mesh)."""
+    env = current_env()
+    if env is None:
+        return 1
+    return int(np.prod([env.mesh.shape[a]
+                        for a in env._mesh_axes_for("batch")]))
 
 
 def map_batch_shards(fn: Callable, *args, batch_args: Sequence[bool]):

@@ -75,7 +75,12 @@ def chunked_causal_attention(
     kv_valid: Optional[jax.Array] = None,  # [B, Skv] bool
     window: Optional[int] = None,
     softmax_scale: Optional[float] = None,
+    segment_ids: Optional[jax.Array] = None,  # [B, S] int, -1 = padding
 ) -> jax.Array:
+    """Causal attention of ``q`` over ``k``/``v``, one query chunk at a
+    time. ``segment_ids`` (self-attention only, ``S == Skv``) packs several
+    sequences into one row: a query sees only keys of its own segment, and
+    no query sees a padding key (-1)."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
     dv = v.shape[-1]  # may differ from hd (MLA)
@@ -85,9 +90,12 @@ def chunked_causal_attention(
     n_chunks = S // qc
     qg = q.reshape(B, n_chunks, qc, KV, G, hd)
     qpos = q_positions.reshape(B, n_chunks, qc)
+    xs = (jnp.moveaxis(qg, 1, 0), jnp.moveaxis(qpos, 1, 0))
+    if segment_ids is not None:
+        xs += (jnp.moveaxis(segment_ids.reshape(B, n_chunks, qc), 1, 0),)
 
     def one_chunk(args):
-        q_i, qpos_i = args  # [B, qc, KV, G, hd], [B, qc]
+        q_i, qpos_i = args[:2]  # [B, qc, KV, G, hd], [B, qc]
         # dtype note: dots stay in the input dtype (TPU MXU accumulates in
         # f32 natively); the explicit upcast happens at the softmax. Using
         # preferred_element_type=f32 here would make every cross-shard
@@ -100,13 +108,16 @@ def chunked_causal_attention(
             mask &= (qpos_i[:, :, None] - kv_positions[:, None, :]) < window
         if kv_valid is not None:
             mask &= kv_valid[:, None, :]
+        if segment_ids is not None:
+            seg_i = args[2]  # [B, qc]
+            mask &= ((seg_i[:, :, None] == segment_ids[:, None, :])
+                     & (segment_ids >= 0)[:, None, :])
         s = jnp.where(mask[:, None, None, :, :], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v)
         return o.astype(q_i.dtype)
 
-    out = jax.lax.map(one_chunk, (jnp.moveaxis(qg, 1, 0),
-                                  jnp.moveaxis(qpos, 1, 0)))
+    out = jax.lax.map(one_chunk, xs)
     out = jnp.moveaxis(out, 0, 1).reshape(B, S, H, dv)
     return out
 
@@ -160,15 +171,16 @@ def _write_cache(cache_arr: jax.Array, new: jax.Array,
 
 
 def attention_full(params, x, cfg: ModelConfig, positions,
-                   pad_mask=None, window=None):
-    """Train/prefill attention over the whole sequence.
+                   pad_mask=None, window=None, segment_ids=None):
+    """Train/prefill attention over the whole sequence (or over rows of
+    packed sequences, given ``segment_ids``).
 
     Returns (out [B,S,d], kv) so prefill can also populate a cache.
     """
     q, k, v = _project_qkv(params, x, cfg, positions)
     out = chunked_causal_attention(
         q, k, v, q_positions=positions, kv_positions=positions,
-        kv_valid=pad_mask, window=window)
+        kv_valid=pad_mask, window=window, segment_ids=segment_ids)
     out = constrain(out, "batch", None, "act_heads", None)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return y, (k, v)

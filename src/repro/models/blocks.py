@@ -40,15 +40,16 @@ def _ffn(params, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
 
 # ------------------------------------------------------------------ full seq
 def attn_block_full(params, x, cfg: ModelConfig, positions, pad_mask=None,
-                    window=None):
-    """Returns (x, aux, kv) with kv the cacheables for prefill."""
+                    window=None, segment_ids=None):
+    """Returns (x, aux, kv) with kv the cacheables for prefill.
+    ``segment_ids`` (packed rows) is for plain attention only."""
     h = rmsnorm(params["ln1"], x, cfg.norm_eps)
     if cfg.mla is not None:
         a_out, kv = mla_mod.mla_full(params["attn"], h, cfg, positions,
                                      pad_mask, window)
     else:
         a_out, kv = attn_mod.attention_full(params["attn"], h, cfg, positions,
-                                            pad_mask, window)
+                                            pad_mask, window, segment_ids)
     if cfg.parallel_block:
         f_out, aux = _ffn(params["ffn"], h, cfg)
         return x + a_out + f_out, aux, kv

@@ -113,13 +113,29 @@ def _embed_inputs(params, cfg: ModelConfig, tokens, embeds):
 
 
 # ------------------------------------------------------------------ full seq
+def packable(cfg: ModelConfig) -> bool:
+    """Whether several sequences may share a row of ``forward_hidden``
+    (``segment_ids``): only a plain attention stack keeps them apart.
+    Recurrent SSM state, capacity-routed experts, latent attention and
+    frontend prefixes would mix or shift them."""
+    return (cfg.arch_type == "dense" and cfg.moe is None and cfg.mla is None
+            and cfg.frontend is None)
+
+
 def forward_hidden(params, cfg: ModelConfig, tokens: jax.Array,
                    embeds: Optional[jax.Array] = None,
                    positions: Optional[jax.Array] = None,
                    pad_mask: Optional[jax.Array] = None,
                    window: Optional[int] = None,
+                   segment_ids: Optional[jax.Array] = None,
                    ) -> Tuple[jax.Array, jax.Array]:
-    """tokens [B,St] (+embeds [B,F,d]) -> (hidden [B,S,d], aux loss)."""
+    """tokens [B,St] (+embeds [B,F,d]) -> (hidden [B,S,d], aux loss).
+
+    ``segment_ids`` [B,S] (-1 = padding) marks rows that pack several
+    sequences, with ``positions`` restarting at 0 in each; attention stays
+    within a segment. Only for a ``packable`` config."""
+    if segment_ids is not None and not packable(cfg):
+        raise ValueError(f"{cfg.name}: sequences cannot share a row")
     x = _embed_inputs(params, cfg, tokens, embeds)
     B, S, _ = x.shape
     if positions is None:
@@ -150,7 +166,7 @@ def forward_hidden(params, cfg: ModelConfig, tokens: jax.Array,
             # matmuls. Default rule is None => no-op.
             h = constrain(h, "batch", "seq_sp", "act_embed")
             h, a, _ = blocks.attn_block_full(layer_params, h, cfg, positions,
-                                             pad_mask, window)
+                                             pad_mask, window, segment_ids)
             return (h, aux + a), None
         if cfg.remat:
             body = jax.checkpoint(body)

@@ -23,6 +23,19 @@ and its requires-flags decide what the step computes at all: only
 ``needs_prox_forward`` algorithms pay the extra forward pass, and only
 ``needs_behav_logp`` / ``needs_versions`` algorithms get those tensors
 threaded through the compiled minibatch scan.
+
+Sequence packing: a rollout batch is padded to its longest possible
+sequence, so most of its positions are padding. For a plain attention
+stack (``models.model.packable``) the compiled step packs each minibatch's
+sequences first-fit-decreasing into its first rows (``first_fit``, by the
+input positions its mask reads), attention kept within each sequence by
+segment ids. The decoder layers run on the fewest whole blocks of rows
+(``row_block``: a quarter of the minibatch) that hold them, and their
+hidden states are unpacked to the padded layout before the output head, so
+the head, the loss and every batch tensor keep their shapes. Each block
+count is a branch of one ``lax.switch`` around the minibatch's value and
+gradient (``_by_rows``), so one program serves any lengths and no plan
+crosses from the host.
 """
 from __future__ import annotations
 
@@ -38,7 +51,12 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, RLConfig
 from repro.core.algorithms import Algorithm, LossInputs, resolve_algorithm
-from repro.distributed.sharding import constrain, current_env
+from repro.data.tokenizer import PAD
+from repro.distributed.sharding import (
+    batch_shard_count,
+    constrain,
+    current_env,
+)
 from repro.kernels.logprob import token_logprob_entropy
 from repro.models import model as M
 from repro.models.layers import output_head_weight
@@ -118,11 +136,143 @@ def assemble_train_batch(rollouts: List[RolloutBatch],
     )
 
 
+# ------------------------------------------------------------------- packing
+def row_block(n: int, shards: int = 1) -> int:
+    """The step the decoder's row count takes over a unit of ``n`` packed
+    sequences: a quarter of ``n``, rounded up to the batch's shard count so
+    packed rows still split over its shards. A single block (``n`` at most)
+    packs nothing."""
+    q = -(-n // 4)
+    return -(-q // shards) * shards
+
+
+def _block(cfg: ModelConfig, n: int) -> Optional[int]:
+    """``row_block`` of a unit of ``n`` sequences under the active mesh, or
+    None where the unit keeps its padded rows: a stack whose rows cannot be
+    shared, or a unit that is one block or not whole blocks."""
+    if not M.packable(cfg):
+        return None
+    q = row_block(n, batch_shard_count())
+    return q if q < n and n % q == 0 else None
+
+
+class RowFit(NamedTuple):
+    """Where first-fit-decreasing puts each sequence of a unit (a minibatch,
+    or a microbatch of one), over the units' leading axes. int32."""
+
+    need: Any     # [..., n] input positions the sequence's loss reads
+    row: Any      # [..., n] its packed row
+    offset: Any   # [..., n] its first position in that row
+    blocks: Any   # [...] row blocks that hold the unit's sequences
+
+
+def input_needs(mask: jax.Array) -> jax.Array:
+    """Input positions each sequence's loss reads, [..., S] -> [...]: all
+    up to the last one its mask keeps (none where it keeps none)."""
+    S = mask.shape[-1]
+    return jnp.max(jnp.where(mask > 0, jnp.arange(1, S + 1, dtype=jnp.int32),
+                             0), axis=-1)
+
+
+def first_fit(need: jax.Array, width: int
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """First-fit-decreasing of sequences of ``need`` [n] positions (each at
+    most ``width``) into rows of ``width``: each one's row and offset [n],
+    and the rows used (at least 1). n rows always hold n sequences, so a
+    row is always found."""
+    n = need.shape[0]
+    order = jnp.argsort(-need, stable=True)
+
+    def place(i, carry):
+        fill, row, offset = carry
+        b = order[i]
+        r = jnp.argmax(fill + need[b] <= width)
+        return (fill.at[r].add(need[b]), row.at[b].set(r),
+                offset.at[b].set(fill[r]))
+
+    zero = jnp.zeros((n,), jnp.int32)
+    fill, row, offset = jax.lax.fori_loop(0, n, place, (zero, zero, zero))
+    # a row opens only when every earlier one is too full for the sequence,
+    # so the rows used are the first ones
+    return row, offset, jnp.maximum(jnp.sum(fill > 0), 1)
+
+
+def fit_rows(mask: jax.Array, block: int) -> RowFit:
+    """``first_fit`` of each unit of ``mask`` [..., n, S] into rows of
+    ``S``, and the blocks of ``block`` rows that hold it."""
+    lead, (n, S) = mask.shape[:-2], mask.shape[-2:]
+    need = input_needs(mask).reshape(-1, n)
+    row, offset, used = jax.vmap(lambda x: first_fit(x, S))(need)
+    blocks = -(-used // block)
+    return RowFit(*(a.reshape(lead + a.shape[1:])
+                    for a in (need, row, offset, blocks)))
+
+
+def pack_index(fit: RowFit, S: int, rows: int
+               ) -> Tuple[jax.Array, jax.Array]:
+    """One unit packed into ``rows`` rows of ``S`` as ``fit`` places it:
+    ``src`` [rows, S], each packed position's place in the unit's inputs
+    flattened to [n*S] (-1: padding), and ``dest`` [n, S], each input
+    position's place in the packed rows flattened (``rows*S``: none)."""
+    n = fit.need.shape[0]
+    col = jnp.arange(S, dtype=jnp.int32)
+    dest = jnp.where(col < fit.need[:, None],
+                     (fit.row * S + fit.offset)[:, None] + col, rows * S)
+    src = jnp.full((rows * S,), -1, jnp.int32).at[dest.reshape(-1)].set(
+        jnp.arange(n * S, dtype=jnp.int32), mode="drop")
+    return src.reshape(rows, S), dest
+
+
+def _packed_hidden(params, cfg: ModelConfig, inputs: jax.Array, fit: RowFit,
+                   rows: int) -> Tuple[jax.Array, jax.Array]:
+    """``forward_hidden`` over ``inputs`` [n, S] packed into ``rows`` rows
+    as ``fit`` places them, unpacked to [n, S, d]. A position past its
+    sequence's need takes a zero hidden state: no loss reads it. The final
+    norm runs on the packed rows; it is per position, so unpacking after it
+    changes nothing."""
+    S = inputs.shape[1]
+    src, dest = pack_index(fit, S, rows)
+    src = constrain(src, "batch", None)
+    ok = src >= 0
+    packed = jnp.where(ok, jnp.take(inputs.reshape(-1), jnp.maximum(src, 0)),
+                       PAD)
+    h, aux = M.forward_hidden(params, cfg, packed,
+                              positions=jnp.where(ok, src % S, 0),
+                              segment_ids=jnp.where(ok, src // S, -1))
+    h = jnp.take(h.reshape(rows * S, -1), jnp.minimum(dest, rows * S - 1),
+                 axis=0)
+    h = jnp.where((dest < rows * S)[..., None], h, 0)
+    return constrain(h, "batch", None, "act_embed"), aux
+
+
+def _by_rows(fn, n: int, block: Optional[int], fit: Optional[RowFit],
+             *args):
+    """``fn(*args, fit, rows=R)`` with the decoder on ``R`` packed rows, the
+    fewest whole blocks that hold the unit ``fit`` places, or
+    ``fn(*args, None, rows=None)`` on its ``n`` padded rows where only all
+    of them do (or ``block`` is None). A branch for each block count is
+    compiled; the lengths pick one."""
+    def padded(*a):
+        return fn(*a[:-1], None, rows=None)
+
+    if block is None:
+        return padded(*args, None)
+    branches = [functools.partial(fn, rows=k * block)
+                for k in range(1, n // block)] + [padded]
+    return jax.lax.switch(fit.blocks - 1, branches, *args, fit)
+
+
 # --------------------------------------------------------------------- score
-def _score_tokens(params, cfg: ModelConfig, tokens: jax.Array
+def _score_tokens(params, cfg: ModelConfig, tokens: jax.Array,
+                  fit: Optional[RowFit] = None, rows: Optional[int] = None
                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Per-position logp and entropy of ``tokens``; the decoder on the
+    padded rows, or on ``rows`` packed rows as ``fit`` places them."""
     tokens = constrain(tokens, "batch", None)
-    hidden, aux = M.forward_hidden(params, cfg, tokens[:, :-1])
+    if rows is None:
+        hidden, aux = M.forward_hidden(params, cfg, tokens[:, :-1])
+    else:
+        hidden, aux = _packed_hidden(params, cfg, tokens[:, :-1], fit, rows)
     w = output_head_weight(params["embedding"], cfg)
     with jax.named_scope("logprob_head"):
         logp, entropy = token_logprob_entropy(hidden, w, tokens[:, 1:])
@@ -143,13 +293,22 @@ def score_tokens(params, cfg: ModelConfig, tokens: jax.Array
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
-def recompute_prox_logp(params, cfg: ModelConfig, tokens: jax.Array
-                        ) -> jax.Array:
+def recompute_prox_logp(params, cfg: ModelConfig, tokens: jax.Array,
+                        mask: Optional[jax.Array] = None) -> jax.Array:
     """The explicit proximal forward pass of decoupled PPO (Hilton 2022).
 
-    This is the per-step cost A-3PO eliminates (paper Fig. 1)."""
-    logp, _, _ = _score_tokens(params, cfg, tokens)
-    return jax.lax.stop_gradient(logp)
+    This is the per-step cost A-3PO eliminates (paper Fig. 1). Given the
+    batch's ``mask``, the layers run on packed rows as in the train step,
+    the whole batch one unit."""
+    n = tokens.shape[0]
+    block = None if mask is None else _block(cfg, n)
+    fit = None if block is None else fit_rows(mask, block)
+
+    def score(params, tokens, fit, rows):
+        return _score_tokens(params, cfg, tokens, fit, rows)[0]
+
+    return jax.lax.stop_gradient(
+        _by_rows(score, n, block, fit, params, tokens))
 
 
 # --------------------------------------------------------------- fused step
@@ -157,8 +316,8 @@ def recompute_prox_logp(params, cfg: ModelConfig, tokens: jax.Array
 # array is the step's one device->host transfer.
 METRIC_KEYS: Tuple[str, ...] = (
     "clipped_frac", "clipped_tokens", "entropy", "grad_norm", "iw_max",
-    "iw_mean", "iw_min", "kl", "loss", "nonfinite", "ratio_mean",
-    "reward_mean", "staleness_mean", "tokens",
+    "iw_mean", "iw_min", "kl", "loss", "nonfinite", "packed_rows",
+    "ratio_mean", "reward_mean", "staleness_mean", "tokens",
 )
 
 
@@ -191,7 +350,9 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
     updates (optionally gradient-accumulated over microbatches) -> packed
     metrics. Exactly one output array carries every scalar metric. The
     ``algo`` static supplies the loss and its requires-flags decide which
-    batch tensors are threaded through the minibatch scan at all."""
+    batch tensors are threaded through the minibatch scan at all. Each
+    minibatch (or microbatch) runs its decoder on the fewest packed rows
+    that hold its sequences (``fit_rows``)."""
     B = tokens.shape[0]
     nmb = num_minibatches
     mb_size = B // nmb
@@ -231,10 +392,18 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
     mbt = jax.tree.map(
         lambda x: x[: nmb * mb_size].reshape((nmb, mb_size) + x.shape[1:]),
         mbt)
+    # where each unit's sequences go in its packed rows
+    unit = mb_size // nmi
+    block = _block(cfg, unit)
+    fit = None
+    if block is not None:
+        lead = (nmb, nmi) if nmi > 1 else (nmb,)
+        fit = fit_rows(mask[: nmb * mb_size].reshape(lead + (unit, -1)),
+                       block)
 
-    def loss_fn(p, t):
+    def loss_fn(p, t, fit, rows):
         t = _constrain_batch(t)
-        logp, entropy, aux = _score_tokens(p, cfg, t["tokens"])
+        logp, entropy, aux = _score_tokens(p, cfg, t["tokens"], fit, rows)
         with jax.named_scope("policy_loss"):
             loss, metrics = algo.loss(logp, LossInputs(
                 advantages=t["advantages"], mask=t["mask"],
@@ -243,9 +412,13 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
                 entropy=entropy), rl)
         return loss + aux, metrics
 
-    def grads_of(p, t):
+    def value_and_grads(p, t, fit, rows):
+        return jax.value_and_grad(functools.partial(loss_fn, rows=rows),
+                                  has_aux=True)(p, t, fit)
+
+    def grads_of(p, t, fit):
         if nmi == 1:
-            return jax.value_and_grad(loss_fn, has_aux=True)(p, t)
+            return _by_rows(value_and_grads, unit, block, fit, p, t)
         micro = jax.tree.map(
             lambda x: x.reshape((nmi, mb_size // nmi) + x.shape[1:]), t)
         g0 = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), p)
@@ -254,25 +427,27 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
         # the losses are masked *means*, so an equal average would
         # over-weight tokens in sparse microbatches relative to the
         # single-pass minibatch objective.
-        def accum(carry, mi):
+        def accum(carry, xs):
+            mi, mfit = xs
             g_acc, loss_acc, w_acc = carry
             w = jnp.sum(mi["mask"])
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(p, mi)
+            (loss, metrics), grads = _by_rows(value_and_grads, unit, block,
+                                              mfit, p, mi)
             g_acc = jax.tree.map(
                 lambda a, g: a + w * g.astype(jnp.float32), g_acc, grads)
             return (g_acc, loss_acc + w * loss, w_acc + w), metrics
 
         (grads, loss, w_tot), ms = jax.lax.scan(
             accum, (g0, jnp.zeros((), jnp.float32),
-                    jnp.zeros((), jnp.float32)), micro)
+                    jnp.zeros((), jnp.float32)), (micro, fit))
         w_tot = jnp.maximum(w_tot, 1.0)
         grads = jax.tree.map(lambda g: g / w_tot, grads)
         return (loss / w_tot, _reduce_metrics(ms)), grads
 
-    def minibatch_body(carry, t):
+    def minibatch_body(carry, xs):
+        t, fit = xs
         p, o = carry
-        (loss, metrics), grads = grads_of(p, t)
+        (loss, metrics), grads = grads_of(p, t, fit)
         with jax.named_scope("optimizer"):
             p2, o2, gnorm = adam_update(grads, o, p, rl)
         # on-device non-finite guard: grad_norm is a global reduction, so
@@ -293,13 +468,18 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
                        nonfinite=(~ok).astype(jnp.float32))
         return (p, o), metrics
 
-    (params, opt), stacked = jax.lax.scan(minibatch_body, (params, opt), mbt)
+    (params, opt), stacked = jax.lax.scan(minibatch_body, (params, opt),
+                                          (mbt, fit))
     out = _reduce_metrics(stacked)
     out["reward_mean"] = rewards.mean()
     out["staleness_mean"] = staleness_mean
     # response tokens that actually received a gradient (rows past
     # nmb * mb_size are dropped from the scan, so don't count them)
     out["tokens"] = jnp.sum(mask[: nmb * mb_size])
+    # rows the decoder ran per pass over the step's units
+    out["packed_rows"] = (
+        jnp.float32(nmb * mb_size) if block is None
+        else jnp.sum(fit.blocks * block).astype(jnp.float32))
     assert set(out) == set(METRIC_KEYS), sorted(out)
     packed = jnp.stack([out[k].astype(jnp.float32) for k in METRIC_KEYS])
     return params, opt, packed
@@ -403,7 +583,7 @@ class Trainer:
         if self.algo.needs_prox_forward:
             with span("prox_forward", algo=self.algo.name):
                 prox = recompute_prox_logp(state.params, self.cfg,
-                                           batch.tokens)
+                                           batch.tokens, batch.response_mask)
                 prox.block_until_ready()
             host_syncs += 1
         prox_time = time.perf_counter() - t0
@@ -411,7 +591,7 @@ class Trainer:
         # train_dispatch returns once the step is enqueued; train_fetch is
         # the host waiting on the device; train_metrics is host bookkeeping
         with span("train_update", algo=self.algo.name,
-                  batch=int(B), minibatches=int(nmb)):
+                  batch=int(B), minibatches=int(nmb)) as update_span:
             step_fn = (_train_step_donating if self.donate_params
                        else _train_step)
             with span("train_dispatch"):
@@ -430,6 +610,11 @@ class Trainer:
             out = {k: float(v) for k, v in zip(METRIC_KEYS, values)}
             out["prox_time_s"] = prox_time
             out["host_syncs"] = float(host_syncs)
+            # the padded rows the decoder's packed rows stand for, and the
+            # rows it ran per unit (minibatch, or microbatch of one)
+            units = nmb * self.num_microbatches
+            out["padded_rows"] = float(B // nmb * nmb)
+            update_span.set(rows=out["packed_rows"] / units)
             self.last_host_syncs = host_syncs
             with span("train_metrics"):
                 self._publish_metrics(out)
@@ -442,7 +627,8 @@ class Trainer:
     # alongside the serving facade.
     _GAUGE_KEYS = ("loss", "reward_mean", "entropy", "grad_norm",
                    "iw_max", "iw_min", "iw_mean", "kl", "clipped_frac",
-                   "ratio_mean", "staleness_mean", "prox_time_s")
+                   "ratio_mean", "staleness_mean", "prox_time_s",
+                   "packed_rows", "padded_rows")
     _COUNTER_KEYS = ("tokens", "clipped_tokens", "host_syncs", "nonfinite")
 
     def _publish_metrics(self, out: Dict[str, float]) -> None:

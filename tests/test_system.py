@@ -222,6 +222,20 @@ def test_sharding_env_divisibility_fallback():
     assert env3.spec((1, 4096), ("batch", "seq")) == P()
 
 
+def test_sharding_env_leaves_size_one_axes_unnamed():
+    """A mesh axis of one device shards nothing, so no spec names it: a
+    placement on it equals the replicated one a jitted step returns."""
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+    from repro.distributed.sharding import ShardingEnv
+    fsdp = ShardingEnv(AbstractMesh((4, 1), ("data", "model")))
+    assert fsdp.spec((4096, 11008), ("embed", "ff")) == P("data")
+    assert fsdp.spec((96, 128), ("heads", "head_dim")) == P()
+    assert fsdp.spec((64, 1344), ("batch", None)) == P("data")
+    one = ShardingEnv(AbstractMesh((1, 1), ("data", "model")))
+    assert one.spec((4096, 11008), ("embed", "ff")) == P()
+    assert one.spec((64, 1344), ("batch", None)) == P()
+
+
 def test_constrain_noop_without_mesh():
     from repro.distributed.sharding import constrain
     x = jnp.ones((4, 4))

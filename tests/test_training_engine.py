@@ -24,6 +24,7 @@ from repro.core.objective import (
     policy_objective,
     resolve_alpha,
 )
+from repro.training import trainer as trainer_mod
 from repro.training.optimizer import adam_update
 from repro.training.trainer import (
     TrainBatch,
@@ -34,6 +35,10 @@ from repro.training.trainer import (
 )
 
 B, T = 8, 12
+# real tokens per row of a ragged batch: each minibatch of 4 packs into 3
+# rows of T - 1 input positions; ``make_batch``'s random mask reads fewer
+# of them, so its minibatches pack into 2
+RAGGED = (6, 7, 12, 5, 4, 8, 6, 10)
 
 
 @pytest.fixture(scope="module")
@@ -46,12 +51,20 @@ def rl():
     return RLConfig(group_size=4, num_minibatches=2, learning_rate=3e-4)
 
 
-def make_batch(per_token_versions: bool, seed: int = 0) -> TrainBatch:
+def make_batch(per_token_versions: bool, seed: int = 0,
+               ragged: bool = False) -> TrainBatch:
+    """Every row full, or ``ragged``: rows of ``RAGGED`` real tokens, PAD
+    after them and no response past them."""
     k = jax.random.PRNGKey(seed)
     ks = jax.random.split(k, 5)
     tokens = jax.random.randint(ks[0], (B, T), 4, 60)
     mask = (jnp.arange(T - 1)[None, :] >= 4).astype(jnp.float32) \
         * (jax.random.uniform(ks[1], (B, T - 1)) > 0.2)
+    if ragged:
+        lengths = jnp.asarray(RAGGED)[:, None]
+        tokens = jnp.where(jnp.arange(T)[None, :] < lengths, tokens, 0)
+        mask = mask * (jnp.arange(T - 1)[None, :] >= 2) \
+            * (jnp.arange(T - 1)[None, :] <= lengths - 2)
     behav = -jax.random.uniform(ks[2], (B, T - 1)) * 2 * mask
     if per_token_versions:
         versions = jax.random.randint(ks[3], (B, T - 1), 0, 4)
@@ -121,10 +134,12 @@ PARITY_KEYS = ("loss", "grad_norm", "iw_max", "iw_min", "iw_mean",
 
 @pytest.mark.parametrize("method", ["loglinear", "recompute", "sync"])
 @pytest.mark.parametrize("per_token", [False, True])
-def test_scan_engine_matches_seed_loop(toy, rl, method, per_token):
+@pytest.mark.parametrize("ragged", [False, True])
+def test_scan_engine_matches_seed_loop(toy, rl, method, per_token, ragged):
     """The compiled scan pipeline reproduces the seed loop-trainer's
-    metrics and parameters for all three methods, [B] and [B,T] stamps."""
-    batch = make_batch(per_token)
+    metrics and parameters for all three methods, [B] and [B,T] stamps;
+    on ragged rows its decoder runs packed against the loop's padding."""
+    batch = make_batch(per_token, ragged=ragged)
     trainer = Trainer(toy, rl, method)
     s_scan = trainer.init_state(jax.random.PRNGKey(3))
     s_ref = trainer.init_state(jax.random.PRNGKey(3))
@@ -134,6 +149,8 @@ def test_scan_engine_matches_seed_loop(toy, rl, method, per_token):
 
     s_ref, m_ref = reference_loop_step(toy, rl, method, s_ref, batch)
     s_scan, m_scan = trainer.step(s_scan, batch)
+    assert m_scan["padded_rows"] == B
+    assert m_scan["packed_rows"] == (4 if ragged else B)
 
     for k in PARITY_KEYS:
         np.testing.assert_allclose(m_scan[k], m_ref[k], rtol=2e-4,
@@ -144,10 +161,29 @@ def test_scan_engine_matches_seed_loop(toy, rl, method, per_token):
                                    atol=1e-6)
 
 
+def assembled_batch() -> TrainBatch:
+    """A ragged batch as ``assemble_train_batch`` builds it from rollouts:
+    the decoder's rows pack."""
+    from repro.rollout.engine import RolloutBatch
+    from repro.training.trainer import assemble_train_batch
+
+    rng = np.random.default_rng(0)
+    P, N = 4, T - 4
+    gen = np.array([L - P for L in RAGGED])
+    gen_mask = (np.arange(N)[None, :] < gen[:, None]).astype(np.float32)
+    tokens = rng.integers(4, 60, (B, T)).astype(np.int32)
+    tokens[np.arange(T)[None, :] >= np.array(RAGGED)[:, None]] = 0
+    rollout = RolloutBatch(
+        tokens=tokens, prompt_lengths=np.full(B, P, np.int32),
+        gen_logp=(-rng.uniform(size=(B, N)) * gen_mask).astype(np.float32),
+        gen_mask=gen_mask, version=1)
+    return assemble_train_batch([rollout], rng.uniform(size=B))
+
+
 def test_one_host_transfer_per_step(toy, rl, monkeypatch):
     """The scan engine performs exactly one device->host transfer per
-    training step (the packed metrics vector)."""
-    batch = make_batch(False)
+    training step (the packed metrics vector), with its decoder packed."""
+    batch = assembled_batch()
     trainer = Trainer(toy, rl, "loglinear")
     state = trainer.init_state(jax.random.PRNGKey(0))
     trainer.step(state, batch)  # warm the compile cache
@@ -160,11 +196,35 @@ def test_one_host_transfer_per_step(toy, rl, monkeypatch):
     _, m = trainer.step(state, batch)
     assert len(calls) == 1
     assert m["host_syncs"] == 1.0
+    assert m["packed_rows"] < m["padded_rows"]
     # recompute pays its explicit prox sync on top
     tr = Trainer(toy, rl, "recompute")
     s2 = tr.init_state(jax.random.PRNGKey(0))
     _, m2 = tr.step(s2, batch)
     assert m2["host_syncs"] == 2.0
+
+
+def test_batch_without_lengths_pays_one_transfer_on_first_step(
+        toy, rl, monkeypatch):
+    """A batch built by hand, not by ``assemble_train_batch``, packs on the
+    device from its mask: its first step, as every later one, pays only
+    the metrics' transfer, and every step runs one compiled program."""
+    batch = make_batch(False, ragged=True)
+    trainer = Trainer(toy, rl, "loglinear")
+    state = trainer.init_state(jax.random.PRNGKey(0))
+    calls = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: (calls.append(1), real(x))[1])
+    state, m = trainer.step(state, batch)
+    assert len(calls) == 1 and m["host_syncs"] == 1.0
+    assert m["packed_rows"] == 4.0
+    programs = trainer_mod._train_step._cache_size()
+    # other lengths, every row full: the padded branch of the same program
+    _, m = trainer.step(state, make_batch(False, seed=1))
+    assert len(calls) == 2 and m["host_syncs"] == 1.0
+    assert m["packed_rows"] == m["padded_rows"] == B
+    assert trainer_mod._train_step._cache_size() == programs
 
 
 def test_fused_gradient_matches_jnp_reference():
