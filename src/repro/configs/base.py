@@ -7,13 +7,20 @@ they can be hashed into jit static args.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts settings (capacity-based top-k routing)."""
+    """Mixture-of-experts settings: drop-free top-k routing over all
+    ``num_experts`` router outputs, of which this layer holds ``held``
+    (first expert, count; None: all of them). A layer that holds a share
+    computes its own experts' part of the result; what the experts held
+    elsewhere add is left out, as on one rank of expert parallelism
+    without its exchange. ``capacity_factor`` bounds only the buffers of
+    the explicit expert-parallel dispatch (``moe_apply_ep``)."""
 
     num_experts: int
     top_k: int
@@ -21,6 +28,49 @@ class MoEConfig:
     num_shared_experts: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 1e-2
+    held: Optional[Tuple[int, int]] = None
+    norm_topk_prob: bool = True  # renormalise the top-k weights to sum 1
+    routed_scaling_factor: float = 1.0
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        """(first expert held, experts held)."""
+        return self.held if self.held is not None else (0, self.num_experts)
+
+    @property
+    def num_held(self) -> int:
+        return self.held_range[1]
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def get_mscale(scale: float, mscale: float) -> float:
+        """DeepSeek-V2's ``yarn_get_mscale``."""
+        return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+    @property
+    def attn_factor(self) -> float:
+        """Factor on the softmax scale: ``mscale(factor, mscale_all_dim)``
+        squared (1 without ``mscale_all_dim``)."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self.get_mscale(self.factor, self.mscale_all_dim) ** 2
+
+    @property
+    def cos_sin_factor(self) -> float:
+        """Factor on the rotary cos and sin."""
+        return (self.get_mscale(self.factor, self.mscale)
+                / self.get_mscale(self.factor, self.mscale_all_dim))
 
 
 @dataclass(frozen=True)
@@ -74,6 +124,11 @@ class ModelConfig:
     qkv_bias: bool = False
     norm_eps: float = 1e-5
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None  # latent attention's rope only
+    # MoE stacks: the first ``first_dense_layers`` layers carry a dense
+    # SwiGLU of width ``d_ff`` in place of the experts (DeepSeek's
+    # ``first_k_dense_replace``)
+    first_dense_layers: int = 0
     sliding_window: Optional[int] = None  # static window; None = full causal
     # Window applied only at the long-context decode shape for full-attention
     # archs (the documented sub-quadratic variant). SSM archs ignore it.
@@ -148,9 +203,11 @@ class ModelConfig:
                 )
                 if self.qkv_bias:
                     per += (self.num_heads + 2 * self.num_kv_heads) * hd
-            ffn_per = self._ffn_params()
+            n_dense = self.first_dense_layers if self.moe is not None else 0
             n_norms = 1 if self.parallel_block else 2
-            p += n_attn_unique * (per + ffn_per + n_norms * d)
+            p += n_attn_unique * (per + n_norms * d)
+            p += (n_attn_unique - n_dense) * self._ffn_params()
+            p += n_dense * 3 * d * self.d_ff
         # ssm blocks
         if n_ssm:
             s = self.ssm or SSMConfig()
@@ -172,7 +229,7 @@ class ModelConfig:
         d = self.d_model
         if self.moe is not None:
             m = self.moe
-            routed = m.num_experts * 3 * d * m.d_ff_expert
+            routed = m.num_held * 3 * d * m.d_ff_expert
             shared = m.num_shared_experts * 3 * d * m.d_ff_expert
             router = d * m.num_experts
             return routed + shared + router
@@ -184,8 +241,9 @@ class ModelConfig:
             return self.num_params()
         m = self.moe
         d = self.d_model
-        inactive = (m.num_experts - m.top_k) * 3 * d * m.d_ff_expert
-        n_moe_layers = sum(1 for k in self.block_kinds() if k == "attn")
+        inactive = max(m.num_held - m.top_k, 0) * 3 * d * m.d_ff_expert
+        n_moe_layers = (sum(1 for k in self.block_kinds() if k == "attn")
+                        - self.first_dense_layers)
         return self.num_params() - n_moe_layers * inactive
 
 
@@ -280,12 +338,13 @@ def reduced(cfg: ModelConfig, *, num_layers: int = 2, d_model: int = 256,
         remat=False,
     )
     if cfg.moe is not None:
-        # capacity_factor 4.0: drop-free routing at smoke scale so the
-        # decode-vs-full consistency check is exact
+        # capacity_factor 4.0: the expert-parallel dispatch drops nothing
+        # at smoke scale either
         changes["moe"] = dataclasses.replace(
             cfg.moe, num_experts=4, top_k=2, d_ff_expert=2 * d_model,
             num_shared_experts=min(cfg.moe.num_shared_experts, 1),
-            capacity_factor=4.0)
+            capacity_factor=4.0, held=None)
+        changes["num_layers"] = num_layers + cfg.first_dense_layers
     if cfg.mla is not None:
         changes["mla"] = MLAConfig(kv_lora_rank=64, qk_nope_head_dim=32,
                                    qk_rope_head_dim=16, v_head_dim=32)

@@ -1,12 +1,13 @@
 """Shared primitive layers: RMSNorm, RoPE, SwiGLU FFN, embeddings."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnConfig
 from repro.distributed.sharding import constrain
 from repro.models.params import ParamSpec
 
@@ -25,18 +26,43 @@ def rmsnorm(params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 
 
 # ----------------------------------------------------------------------- rope
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
+def rope_freqs(head_dim: int, theta: float,
+               scaling: Optional[YarnConfig] = None) -> jax.Array:
+    """Rotary inverse frequencies [head_dim // 2]. With YaRN ``scaling``,
+    DeepSeek-V2's blend (``DeepseekV2YarnRotaryEmbedding``): the bands
+    that turn fewer than ``beta_slow`` times over the original context
+    are interpolated (divided by ``factor``), those that turn more than
+    ``beta_fast`` times keep their frequency, and a linear ramp joins
+    them."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    extra = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if scaling is None:
+        return extra
+
+    def dim_of(rotations):  # yarn_find_correction_dim
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)  # 0 where the band is kept
+    return extra / scaling.factor * ramp + extra * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling: Optional[YarnConfig] = None) -> jax.Array:
     """x: [..., seq, heads, head_dim]; positions: [..., seq] (int)."""
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)  # [half]
+    freqs = rope_freqs(head_dim, theta, scaling)  # [half]
     angles = positions.astype(jnp.float32)[..., None] * freqs  # [..., S, half]
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None and scaling.cos_sin_factor != 1.0:
+        cos, sin = cos * scaling.cos_sin_factor, sin * scaling.cos_sin_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

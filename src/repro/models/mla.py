@@ -45,20 +45,31 @@ def _latent(params, x, cfg: ModelConfig, positions):
     dkv = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"])
     c_kv, k_rope = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
     c_kv = rmsnorm(params["kv_norm"], c_kv, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None], positions,
-                        cfg.rope_theta)[:, :, 0]
+    k_rope = apply_rope(k_rope[:, :, None], positions, cfg.rope_theta,
+                        cfg.rope_scaling)[:, :, 0]
     return c_kv, k_rope
 
 
+def softmax_scale(cfg: ModelConfig) -> float:
+    """``(qk_nope + qk_rope) ** -0.5``, times YaRN's ``mscale ** 2``."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if cfg.rope_scaling is not None:
+        scale *= cfg.rope_scaling.attn_factor
+    return scale
+
+
 def mla_full(params, x, cfg: ModelConfig, positions, pad_mask=None,
-             window=None) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """Train/prefill MLA. Returns (out, (c_kv, k_rope)) for cache handoff."""
+             window=None, segment_ids=None
+             ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """Train/prefill MLA. Returns (out, (c_kv, k_rope)) for cache handoff.
+    ``segment_ids`` (packed rows) keeps attention within a segment."""
     m = cfg.mla
     B, S, _ = x.shape
     h = cfg.num_heads
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     c_kv, k_rope = _latent(params, x, cfg, positions)
     k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, params["w_uk"])
@@ -69,10 +80,10 @@ def mla_full(params, x, cfg: ModelConfig, positions, pad_mask=None,
     qc = jnp.concatenate([q_nope, q_rope], axis=-1)
     qc = constrain(qc, "batch", None, "act_heads", None)
     k = constrain(k, "batch", None, "act_heads", None)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
     out = chunked_causal_attention(
         qc, k, v, q_positions=positions, kv_positions=positions,
-        kv_valid=pad_mask, window=window, softmax_scale=scale)
+        kv_valid=pad_mask, window=window, softmax_scale=softmax_scale(cfg),
+        segment_ids=segment_ids)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return y, (c_kv, k_rope)
 
@@ -87,8 +98,8 @@ def mla_decode(params, x, cfg: ModelConfig, cache: Dict[str, Any],
     B, _ = x.shape
     q = jnp.einsum("bd,dhk->bhk", x, params["wq"])
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope[:, None], lengths[:, None],
-                        cfg.rope_theta)[:, 0]
+    q_rope = apply_rope(q_rope[:, None], lengths[:, None], cfg.rope_theta,
+                        cfg.rope_scaling)[:, 0]
 
     c_kv_t, k_rope_t = _latent(params, x[:, None], cfg, lengths[:, None])
     c_kv_t, k_rope_t = c_kv_t[:, 0], k_rope_t[:, 0]
@@ -106,7 +117,7 @@ def mla_decode(params, x, cfg: ModelConfig, cache: Dict[str, Any],
 
     # absorb W_uk into the query: score = (q_nope W_uk) . c_kv + q_rope . k_rope
     q_lat = jnp.einsum("bhk,rhk->bhr", q_nope, params["w_uk"])
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = softmax_scale(cfg)
     s = (jnp.einsum("bhr,blr->bhl", q_lat, ckv).astype(jnp.float32)
          + jnp.einsum("bhp,blp->bhl", q_rope, krope).astype(jnp.float32)
          ) * scale

@@ -75,9 +75,25 @@ def model_spec(cfg: ModelConfig) -> SpecTree:
         spec["blocks"] = stack_specs(blocks.ssm_block_spec(cfg),
                                      cfg.num_layers)
     else:
+        k = _dense_lead(cfg)
+        if k:
+            spec["dense_blocks"] = stack_specs(
+                blocks.attn_block_spec(cfg, dense=True), k)
         spec["blocks"] = stack_specs(blocks.attn_block_spec(cfg),
-                                     cfg.num_layers)
+                                     cfg.num_layers - k)
     return spec
+
+
+def _dense_lead(cfg: ModelConfig) -> int:
+    """Leading dense layers of an MoE stack."""
+    return cfg.first_dense_layers if cfg.moe is not None else 0
+
+
+def _attn_stacks(params, cfg: ModelConfig):
+    """(stacked params, dense) of each attention stack in layer order: the
+    leading dense layers of an MoE stack, then the rest."""
+    lead = [(params["dense_blocks"], True)] if _dense_lead(cfg) else []
+    return lead + [(params["blocks"], False)]
 
 
 @functools.lru_cache(maxsize=64)
@@ -115,11 +131,10 @@ def _embed_inputs(params, cfg: ModelConfig, tokens, embeds):
 # ------------------------------------------------------------------ full seq
 def packable(cfg: ModelConfig) -> bool:
     """Whether several sequences may share a row of ``forward_hidden``
-    (``segment_ids``): only a plain attention stack keeps them apart.
-    Recurrent SSM state, capacity-routed experts, latent attention and
+    (``segment_ids``): an attention stack, plain or latent, with dense or
+    drop-free routed FFNs, keeps them apart. Recurrent SSM state and
     frontend prefixes would mix or shift them."""
-    return (cfg.arch_type == "dense" and cfg.moe is None and cfg.mla is None
-            and cfg.frontend is None)
+    return cfg.arch_type in ("dense", "moe") and cfg.frontend is None
 
 
 def forward_hidden(params, cfg: ModelConfig, tokens: jax.Array,
@@ -128,19 +143,22 @@ def forward_hidden(params, cfg: ModelConfig, tokens: jax.Array,
                    pad_mask: Optional[jax.Array] = None,
                    window: Optional[int] = None,
                    segment_ids: Optional[jax.Array] = None,
-                   ) -> Tuple[jax.Array, jax.Array]:
-    """tokens [B,St] (+embeds [B,F,d]) -> (hidden [B,S,d], aux loss).
+                   with_stats: bool = False):
+    """tokens [B,St] (+embeds [B,F,d]) -> (hidden [B,S,d], aux loss), and
+    with ``with_stats`` the expert layers' counters (``moe.STATS``; none
+    for a stack without experts) third.
 
     ``segment_ids`` [B,S] (-1 = padding) marks rows that pack several
     sequences, with ``positions`` restarting at 0 in each; attention stays
-    within a segment. Only for a ``packable`` config."""
+    within a segment and padding is routed to no expert, as where
+    ``pad_mask`` is false. Only for a ``packable`` config."""
     if segment_ids is not None and not packable(cfg):
         raise ValueError(f"{cfg.name}: sequences cannot share a row")
     x = _embed_inputs(params, cfg, tokens, embeds)
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    aux0 = jnp.zeros((), jnp.float32)
+    aux0 = blocks.zero_aux(cfg)
 
     if cfg.arch_type == "hybrid":
         with jax.named_scope("decoder"):
@@ -150,36 +168,44 @@ def forward_hidden(params, cfg: ModelConfig, tokens: jax.Array,
         def body(carry, layer_params):
             h, aux = carry
             h, a, _ = blocks.ssm_block_full(layer_params, h, cfg, pad_mask)
-            return (h, aux + a), None
+            return (h, blocks.add_aux(aux, a)), None
         if cfg.remat:
             body = jax.checkpoint(body)
         with jax.named_scope("decoder"):
             (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
     else:
-        def body(carry, layer_params):
-            h, aux = carry
-            # sequence-parallel region boundary: under the opt-in
-            # ("seq_sp" -> "model") rule the residual stream (and hence
-            # the remat-stored layer inputs) is seq-sharded between
-            # blocks; GSPMD turns the TP all-reduces into
-            # reduce-scatter/all-gather pairs around the attention/FFN
-            # matmuls. Default rule is None => no-op.
-            h = constrain(h, "batch", "seq_sp", "act_embed")
-            h, a, _ = blocks.attn_block_full(layer_params, h, cfg, positions,
-                                             pad_mask, window, segment_ids)
-            return (h, aux + a), None
-        if cfg.remat:
-            body = jax.checkpoint(body)
+        def stack(x, aux, stacked, dense):
+            def body(carry, layer_params):
+                h, aux = carry
+                # sequence-parallel region boundary: under the opt-in
+                # ("seq_sp" -> "model") rule the residual stream (and
+                # hence the remat-stored layer inputs) is seq-sharded
+                # between blocks; GSPMD turns the TP all-reduces into
+                # reduce-scatter/all-gather pairs around the attention/FFN
+                # matmuls. Default rule is None => no-op.
+                h = constrain(h, "batch", "seq_sp", "act_embed")
+                h, a, _ = blocks.attn_block_full(
+                    layer_params, h, cfg, positions, pad_mask, window,
+                    segment_ids, dense)
+                return (h, blocks.add_aux(aux, a)), None
+            if cfg.remat:
+                body = jax.checkpoint(body)
+            return jax.lax.scan(body, (x, aux), stacked)[0]
+
         with jax.named_scope("decoder"):
-            (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
+            aux = aux0
+            for stacked, dense in _attn_stacks(params, cfg):
+                x, aux = stack(x, aux, stacked, dense)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return x, aux
+    if with_stats:
+        return x, aux["loss"], {k: v for k, v in aux.items() if k != "loss"}
+    return x, aux["loss"]
 
 
 def _hybrid_full(params, cfg, x, positions, pad_mask, window):
     n_attn, n_ssm, n_periods, per, tail = _layout(cfg)
-    aux0 = jnp.zeros((), jnp.float32)
+    aux0 = blocks.zero_aux(cfg)
     main = jax.tree.map(
         lambda a: a[: n_periods * per].reshape((n_periods, per) + a.shape[1:]),
         params["ssm_blocks"])
@@ -188,14 +214,14 @@ def _hybrid_full(params, cfg, x, positions, pad_mask, window):
     def ssm_body(carry, layer_params):
         h, aux = carry
         h, a, _ = blocks.ssm_block_full(layer_params, h, cfg, pad_mask)
-        return (h, aux + a), None
+        return (h, blocks.add_aux(aux, a)), None
 
     def period_body(carry, period_params):
         h, aux = carry
         (h, aux), _ = jax.lax.scan(ssm_body, (h, aux), period_params)
         h, a, _ = blocks.attn_block_full(params["shared_attn"], h, cfg,
                                          positions, pad_mask, window)
-        return (h, aux + a), None
+        return (h, blocks.add_aux(aux, a)), None
 
     if cfg.remat:
         period_body = jax.checkpoint(period_body)
@@ -270,7 +296,6 @@ def decode_step(params, cfg: ModelConfig, cache: Dict[str, Any],
     lengths = cache["lengths"]
     x = embed_tokens(params["embedding"], tokens[:, None], cfg)[:, 0]
     x = constrain(x, "batch", "act_embed")
-    aux0 = jnp.zeros((), jnp.float32)
     new_cache = dict(cache)
 
     if cfg.arch_type == "hybrid":
@@ -286,21 +311,33 @@ def decode_step(params, cfg: ModelConfig, cache: Dict[str, Any],
                                     (params["blocks"], cache["ssm"]))
         new_cache["ssm"] = ssm_cache
     else:
-        def body(carry, xs):
-            h = carry
-            layer_params, layer_cache = xs
-            h, _, layer_cache = blocks.attn_block_decode(
-                layer_params, h, cfg, layer_cache, lengths, window)
-            return h, layer_cache
-        x, attn_cache = jax.lax.scan(body, x,
-                                     (params["blocks"], cache["attn"]))
-        new_cache["attn"] = attn_cache
+        done, caches = 0, []
+        for stacked, dense in _attn_stacks(params, cfg):
+            def body(carry, xs, dense=dense):
+                h = carry
+                layer_params, layer_cache = xs
+                h, _, layer_cache = blocks.attn_block_decode(
+                    layer_params, h, cfg, layer_cache, lengths, window,
+                    dense)
+                return h, layer_cache
+            n = jax.tree.leaves(stacked)[0].shape[0]
+            x, c = jax.lax.scan(body, x, (stacked, jax.tree.map(
+                lambda a: a[done:done + n], cache["attn"])))
+            done += n
+            caches.append(c)
+        new_cache["attn"] = _concat_layers(caches)
 
     x = rmsnorm(params["final_norm"], x[:, None], cfg.norm_eps)[:, 0]
     logits = logits_from_hidden(params["embedding"], x, cfg)
     new_cache["lengths"] = lengths + 1
-    del aux0
     return logits, new_cache
+
+
+def _concat_layers(trees):
+    """Stacked per-layer trees joined along the layer axis."""
+    if len(trees) == 1:
+        return trees[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *trees)
 
 
 def _hybrid_decode(params, cfg, x, cache, lengths, window):
@@ -387,7 +424,7 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array,
             out[name] = buf
         return out
 
-    aux0 = jnp.zeros((), jnp.float32)
+    aux0 = blocks.zero_aux(cfg)
     cache: Dict[str, Any] = {}
     if cfg.arch_type == "hybrid":
         x, attn_c, ssm_c = _hybrid_prefill(params, cfg, x, positions,
@@ -397,21 +434,25 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array,
         def body(carry, layer_params):
             h, aux = carry
             h, a, c = blocks.ssm_block_full(layer_params, h, cfg, pad_mask)
-            return (h, aux + a), c
+            return (h, blocks.add_aux(aux, a)), c
         (x, _), ssm_c = jax.lax.scan(body, (x, aux0), params["blocks"])
         cache["ssm"] = ssm_c
     else:
-        def body(carry, layer_params):
-            h, aux = carry
-            h, a, kv = blocks.attn_block_full(layer_params, h, cfg,
-                                              positions, pad_mask, window)
-            if cfg.mla is not None:
-                kv = write_kv({"ckv": kv[0], "krope": kv[1]})
-            else:
-                kv = write_kv({"k": kv[0], "v": kv[1]})
-            return (h, aux + a), kv
-        (x, _), attn_c = jax.lax.scan(body, (x, aux0), params["blocks"])
-        cache["attn"] = attn_c
+        caches = []
+        for stacked, dense in _attn_stacks(params, cfg):
+            def body(carry, layer_params, dense=dense):
+                h, aux = carry
+                h, a, kv = blocks.attn_block_full(
+                    layer_params, h, cfg, positions, pad_mask, window,
+                    dense=dense)
+                if cfg.mla is not None:
+                    kv = write_kv({"ckv": kv[0], "krope": kv[1]})
+                else:
+                    kv = write_kv({"k": kv[0], "v": kv[1]})
+                return (h, blocks.add_aux(aux, a)), kv
+            (x, _), c = jax.lax.scan(body, (x, aux0), stacked)
+            caches.append(c)
+        cache["attn"] = _concat_layers(caches)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     cache["lengths"] = lengths
@@ -420,7 +461,7 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array,
 
 def _hybrid_prefill(params, cfg, x, positions, pad_mask, window, write_kv):
     n_attn, n_ssm, n_periods, per, tail = _layout(cfg)
-    aux0 = jnp.zeros((), jnp.float32)
+    aux0 = blocks.zero_aux(cfg)
     main = jax.tree.map(
         lambda a: a[: n_periods * per].reshape((n_periods, per) + a.shape[1:]),
         params["ssm_blocks"])
@@ -430,14 +471,16 @@ def _hybrid_prefill(params, cfg, x, positions, pad_mask, window, write_kv):
     def ssm_body(carry, layer_params):
         h, aux = carry
         h, a, c = blocks.ssm_block_full(layer_params, h, cfg, pad_mask)
-        return (h, aux + a), c
+        return (h, blocks.add_aux(aux, a)), c
 
     def period_body(carry, period_params):
         h, aux = carry
         (h, aux), ssm_c = jax.lax.scan(ssm_body, (h, aux), period_params)
         h, a, kv = blocks.attn_block_full(params["shared_attn"], h, cfg,
                                           positions, pad_mask, window)
-        return (h, aux + a), (ssm_c, write_kv({"k": kv[0], "v": kv[1]}))
+        return (h, blocks.add_aux(aux, a)), (ssm_c,
+                                             write_kv({"k": kv[0],
+                                                       "v": kv[1]}))
 
     (x, aux), (main_ssm_c, attn_c) = jax.lax.scan(period_body, (x, aux0),
                                                   main)

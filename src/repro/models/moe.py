@@ -1,48 +1,59 @@
-"""Top-k MoE with capacity-based dispatch (+ shared experts).
+"""Top-k MoE (+ shared experts), drop-free.
 
 Two dispatch paths:
 
-* ``moe_apply_gspmd`` — sort/scatter into a dense [E, C, d] buffer and let
-  GSPMD reshard. Simple and correct, but XLA implements the cross-shard
-  scatter as replicate+all-reduce of the WHOLE buffer (measured 21.6 TiB
-  of all-reduce per step for qwen3-moe train_4k — see EXPERIMENTS.md
-  §Perf).
+* ``moe_apply_local`` — the layer over the experts held here
+  (``MoEConfig.held``): every real token is routed over all of the
+  router's outputs, the assignments to held experts are sorted by expert
+  and run as grouped matmuls (``jax.lax.ragged_dot``) whose work follows
+  the rows routed here, and the results are scattered back weighted. No
+  token is dropped: the sorted buffer is sized for the worst case,
+  ``T * min(top_k, held)`` rows. The experts held elsewhere are left out,
+  so a share of the experts gives its part of the layer's result, as one
+  rank of expert parallelism does before its exchange.
 * ``moe_apply_ep`` — explicit expert-parallel shard_map: tokens are
   seq-sharded over the "model" axis, routed pairs are bucketed by
   destination rank and exchanged with ``jax.lax.all_to_all``, experts
-  compute locally, and a reverse all-to-all brings results home. This is
-  the production TPU MoE pattern; collective traffic drops to the
-  information-theoretic k-copies-of-tokens volume.
+  compute locally, and a reverse all-to-all brings results home. Its
+  buckets are bounded by ``capacity_factor``.
 
-``moe_apply`` picks EP when the active ShardingEnv requests it and the
-shapes allow (seq divisible by the model axis), else GSPMD.
+``moe_apply`` picks EP when the active ShardingEnv requests it, the layer
+holds every expert and the shapes allow (seq at least the model axis),
+else the local path. Both return ``(y, aux, stats)``: ``stats`` counts the
+assignments computed (``moe_local_rows``) and the most any one expert got
+(``moe_expert_rows_max``).
 """
 from __future__ import annotations
 
 import math
-from functools import partial
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, MoEConfig
-from repro.distributed.sharding import constrain, current_env
+from repro.distributed.sharding import current_env
 from repro.models.layers import swiglu, swiglu_spec
 from repro.models.params import ParamSpec
+
+STATS = ("moe_local_rows", "moe_expert_rows_max")
+# megablox row tile, and the contraction and output tile, capped at the
+# widths (the kernel masks a ragged last tile; its backward swaps the two)
+GMM_ROWS, GMM_COLS = 512, 1024
 
 
 def moe_spec(cfg: ModelConfig) -> Dict[str, Any]:
     m = cfg.moe
-    d = cfg.d_model
+    d, n = cfg.d_model, m.num_held
     spec: Dict[str, Any] = {
         "router": ParamSpec((d, m.num_experts), ("embed", "experts")),
-        "w_gate": ParamSpec((m.num_experts, d, m.d_ff_expert),
+        "w_gate": ParamSpec((n, d, m.d_ff_expert),
                             ("experts", "embed", "expert_ff")),
-        "w_up": ParamSpec((m.num_experts, d, m.d_ff_expert),
+        "w_up": ParamSpec((n, d, m.d_ff_expert),
                           ("experts", "embed", "expert_ff")),
-        "w_down": ParamSpec((m.num_experts, m.d_ff_expert, d),
+        "w_down": ParamSpec((n, m.d_ff_expert, d),
                             ("experts", "expert_ff", "embed")),
     }
     if m.num_shared_experts > 0:
@@ -50,87 +61,125 @@ def moe_spec(cfg: ModelConfig) -> Dict[str, Any]:
     return spec
 
 
-def capacity(m: MoEConfig, num_tokens: int) -> int:
-    c = int(math.ceil(m.top_k * num_tokens / m.num_experts
-                      * m.capacity_factor))
-    return max(c, m.top_k)
-
-
 def route(router_w: jax.Array, x_flat: jax.Array, m: MoEConfig
           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (probs [T,E] f32, topk weights [T,k], topk idx [T,k])."""
+    """Softmax over every router output, greedy top-k. Returns (probs
+    [T,E] f32, top-k weights [T,k], top-k idx [T,k]); the weights are
+    renormalised to sum 1 when ``norm_topk_prob``, then scaled by
+    ``routed_scaling_factor``."""
     logits = jnp.einsum("td,de->te", x_flat, router_w,
                         preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     top_w, top_i = jax.lax.top_k(probs, m.top_k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    if m.routed_scaling_factor != 1.0:
+        top_w = top_w * m.routed_scaling_factor
     return probs, top_w, top_i
 
 
-def load_balance_loss(probs: jax.Array, top_i: jax.Array, m: MoEConfig
-                      ) -> jax.Array:
-    """Switch-style aux loss: E * sum_e f_e * P_e."""
+def load_balance_loss(probs: jax.Array, top_i: jax.Array, m: MoEConfig,
+                      valid: Optional[jax.Array] = None) -> jax.Array:
+    """Switch-style aux loss over the real tokens (``valid`` [T], all
+    when None): E * sum_e f_e * P_e, with f_e the share of the top-k
+    assignments that go to expert e and P_e its mean probability."""
     T = probs.shape[0]
+    w = (jnp.ones((T,), jnp.float32) if valid is None
+         else valid.astype(jnp.float32))
+    n = jnp.maximum(jnp.sum(w), 1.0)
     counts = jnp.zeros((m.num_experts,), jnp.float32).at[
-        top_i.reshape(-1)].add(1.0)
-    f = counts / (T * m.top_k)
-    p = probs.mean(axis=0)
+        top_i.reshape(-1)].add(jnp.repeat(w, m.top_k))
+    f = counts / (n * m.top_k)
+    p = jnp.sum(probs * w[:, None], axis=0) / n
     return m.num_experts * jnp.sum(f * p)
 
 
-def moe_apply(params, x: jax.Array, cfg: ModelConfig
-              ) -> Tuple[jax.Array, jax.Array]:
-    """x: [B, S, d] -> (y, aux_loss). Chooses EP shard_map vs GSPMD."""
+def _use_kernel() -> bool:
+    """The Pallas grouped matmul runs on one TPU chip; XLA partitions its
+    own ragged dot over a mesh, not a Pallas call."""
     env = current_env()
+    return (jax.default_backend() == "tpu"
+            and (env is None or env.mesh.size == 1))
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array
+                   ) -> jax.Array:
+    """lhs [M, k] with its rows sorted by group, rhs [G, k, n], sizes [G]
+    -> [M, n]; rows past ``sum(sizes)`` are undefined. On one TPU chip the
+    megablox kernel, which visits only the row tiles its groups cover
+    (XLA's ragged dot took 2.8x as long over the train cell's expert layer
+    on a v5e, and its ops lose the caller's scopes); elsewhere
+    ``jax.lax.ragged_dot``."""
+    if not _use_kernel():
+        return jax.lax.ragged_dot(lhs, rhs, sizes)
+    m, k = lhs.shape
+    t = min(GMM_COLS, k, rhs.shape[2])
+    out = gmm(jnp.pad(lhs, ((0, -m % GMM_ROWS), (0, 0))), rhs, sizes,
+              preferred_element_type=lhs.dtype, tiling=(GMM_ROWS, t, t))
+    return out[:m]
+
+
+def moe_apply(params, x: jax.Array, cfg: ModelConfig,
+              valid: Optional[jax.Array] = None
+              ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """x: [B, S, d] -> (y, aux_loss, stats); ``valid`` [B, S] marks the
+    real tokens (padding is routed nowhere). Chooses EP shard_map vs the
+    local drop-free path."""
+    env = current_env()
+    m = cfg.moe
     if (env is not None and getattr(env, "ep_shard_map", False)
-            and "model" in env.mesh.axis_names):
+            and "model" in env.mesh.axis_names
+            and m.num_held == m.num_experts):
         n_ranks = env.mesh.shape["model"]
-        if cfg.moe.num_experts % n_ranks == 0 and x.shape[1] >= n_ranks:
+        if m.num_experts % n_ranks == 0 and x.shape[1] >= n_ranks:
             return moe_apply_ep(params, x, cfg, env)
-    return moe_apply_gspmd(params, x, cfg)
+    return moe_apply_local(params, x, cfg, valid)
 
 
-def moe_apply_gspmd(params, x: jax.Array, cfg: ModelConfig
-                    ) -> Tuple[jax.Array, jax.Array]:
-    """x: [B, S, d] -> (y, aux_loss)."""
+def moe_apply_local(params, x: jax.Array, cfg: ModelConfig,
+                    valid: Optional[jax.Array] = None
+                    ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """The layer over the experts held here, drop-free. x: [B, S, d]."""
     m = cfg.moe
     B, S, d = x.shape
-    T = B * S
+    T, k = B * S, m.top_k
+    first, n_here = m.held_range
+    rows = T * min(k, n_here)  # the most assignments that can land here
     xf = x.reshape(T, d)
-    probs, top_w, top_i = route(params["router"], xf, m)
-    aux = load_balance_loss(probs, top_i, m) * m.router_aux_weight
+    ok = None if valid is None else valid.reshape(T)
 
-    C = capacity(m, T)
-    E = m.num_experts
-    N = T * m.top_k
-    flat_e = top_i.reshape(N)
-    flat_w = top_w.reshape(N).astype(x.dtype)
-    flat_t = jnp.repeat(jnp.arange(T), m.top_k)
+    with jax.named_scope("moe_route"):
+        probs, top_w, top_i = route(params["router"], xf, m)
+        aux = load_balance_loss(probs, top_i, m, ok) * m.router_aux_weight
+        local = top_i - first
+        mine = (local >= 0) & (local < n_here)
+        if ok is not None:
+            mine &= ok[:, None]
+        # assignments sorted by local expert; the rest (key n_here) last
+        key = jnp.where(mine, local, n_here).reshape(T * k)
+        order = jnp.argsort(key, stable=True)[:rows]
+        sizes = jnp.zeros((n_here + 1,), jnp.int32).at[key].add(1)[:n_here]
+        live = jnp.arange(rows) < jnp.sum(sizes)
+        tok = order // k
+        xs = jnp.where(live[:, None], jnp.take(xf, tok, axis=0), 0)
+        w = jnp.where(live, top_w.reshape(T * k)[order], 0.0).astype(x.dtype)
 
-    order = jnp.argsort(flat_e, stable=True)
-    se, st, sw = flat_e[order], flat_t[order], flat_w[order]
-    starts = jnp.searchsorted(se, jnp.arange(E), side="left")
-    pos = jnp.arange(N) - starts[se]
-    slot = jnp.where(pos < C, se * C + pos, E * C)  # overflow -> trash row
+    with jax.named_scope("moe_experts"):
+        g = grouped_matmul(xs, params["w_gate"], sizes)
+        u = grouped_matmul(xs, params["w_up"], sizes)
+        ys = grouped_matmul(jax.nn.silu(g) * u, params["w_down"], sizes)
 
-    buf = jnp.zeros((E * C + 1, d), x.dtype).at[slot].set(xf[st])
-    xe = buf[: E * C].reshape(E, C, d)
-    xe = constrain(xe, "experts", None, None)
-
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, params["w_gate"])) \
-        * jnp.einsum("ecd,edf->ecf", xe, params["w_up"])
-    h = constrain(h, "experts", None, None)
-    ye = jnp.einsum("ecf,efd->ecd", h, params["w_down"])
-    ye = constrain(ye, "experts", None, None)
-
-    padded = jnp.concatenate(
-        [ye.reshape(E * C, d), jnp.zeros((1, d), ye.dtype)], axis=0)
-    y_pairs = padded[slot] * sw[:, None]
-    y = jnp.zeros((T, d), x.dtype).at[st].add(y_pairs)
+    with jax.named_scope("moe_combine"):
+        # rows past the routed ones are never computed: mask, not multiply
+        ys = jnp.where(live[:, None], ys, 0) * w[:, None]
+        y = jnp.zeros((T, d), x.dtype).at[tok].add(ys.astype(x.dtype))
 
     if m.num_shared_experts > 0:
-        y = y + swiglu(params["shared"], xf)
-    return y.reshape(B, S, d), aux
+        with jax.named_scope("moe_shared"):
+            y = y + swiglu(params["shared"], xf)
+    stats = {"moe_local_rows": jnp.sum(sizes),
+             "moe_expert_rows_max": jnp.max(sizes)}
+    return y.reshape(B, S, d), aux, stats
 
 
 # ---------------------------------------------------------------- EP path
@@ -164,6 +213,8 @@ def _ep_body(x, router_w, w_gate, w_up, w_down, *, m: MoEConfig,
     probs, top_w, top_i = route(router_w, x, m)
     aux = load_balance_loss(probs, top_i, m) * m.router_aux_weight
     aux = jax.lax.pmean(aux, all_axes)
+    counts = jax.lax.psum(jnp.zeros((m.num_experts,), jnp.int32).at[
+        top_i.reshape(-1)].add(1), all_axes)
 
     N = T * k
     flat_e = top_i.reshape(N)
@@ -215,11 +266,11 @@ def _ep_body(x, router_w, w_gate, w_up, w_down, *, m: MoEConfig,
     inv = jnp.argsort(order, stable=True)
     y_pairs = y_pairs_sorted[inv] * flat_w[:, None]
     y = jnp.zeros((T, d), x.dtype).at[flat_t].add(y_pairs)
-    return y, aux
+    return y, aux, counts
 
 
 def moe_apply_ep(params, x: jax.Array, cfg: ModelConfig, env
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Expert-parallel MoE via shard_map + all_to_all over 'model'."""
     from jax.experimental.shard_map import shard_map
 
@@ -239,21 +290,24 @@ def moe_apply_ep(params, x: jax.Array, cfg: ModelConfig, env
 
     def body(x_blk, router_w, w_gate, w_up, w_down):
         T = x_blk.shape[0] * x_blk.shape[1]
-        y, aux = _ep_body(x_blk.reshape(T, d), router_w, w_gate, w_up,
-                          w_down, m=m, n_ranks=n_ranks, all_axes=all_axes)
-        return y.reshape(x_blk.shape), aux
+        y, aux, counts = _ep_body(x_blk.reshape(T, d), router_w, w_gate,
+                                  w_up, w_down, m=m, n_ranks=n_ranks,
+                                  all_axes=all_axes)
+        return y.reshape(x_blk.shape), aux, counts
 
     x_spec = P(batch_axes if batch_axes else None, "model", None)
     mapped = shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(), P("model"), P("model"), P("model")),
-        out_specs=(x_spec, P()),
+        out_specs=(x_spec, P(), P()),
         check_rep=False)
-    y, aux = mapped(x, params["router"], params["w_gate"], params["w_up"],
+    y, aux, counts = mapped(x, params["router"], params["w_gate"], params["w_up"],
                     params["w_down"])
     if m.num_shared_experts > 0:
         y = y + swiglu(params["shared"], x.reshape(B * S, d)
                        ).reshape(B, S, d)
     if S != orig_S:
         y = y[:, :orig_S]
-    return y, aux
+    stats = {"moe_local_rows": jnp.sum(counts),
+             "moe_expert_rows_max": jnp.max(counts)}
+    return y, aux, stats

@@ -25,8 +25,9 @@ and its requires-flags decide what the step computes at all: only
 threaded through the compiled minibatch scan.
 
 Sequence packing: a rollout batch is padded to its longest possible
-sequence, so most of its positions are padding. For a plain attention
-stack (``models.model.packable``) the compiled step packs each minibatch's
+sequence, so most of its positions are padding. For an attention stack
+(``models.model.packable``: plain or latent attention, dense or drop-free
+routed FFNs) the compiled step packs each minibatch's
 sequences first-fit-decreasing into its first rows (``first_fit``, by the
 input positions its mask reads), attention kept within each sequence by
 segment ids. The decoder layers run on the fewest whole blocks of rows
@@ -60,6 +61,7 @@ from repro.distributed.sharding import (
 from repro.kernels.logprob import token_logprob_entropy
 from repro.models import model as M
 from repro.models.layers import output_head_weight
+from repro.models.moe import STATS as MOE_STATS
 from repro.obs.metrics import get_registry
 from repro.obs.tracing import span
 from repro.rollout.engine import RolloutBatch
@@ -224,25 +226,26 @@ def pack_index(fit: RowFit, S: int, rows: int
 
 
 def _packed_hidden(params, cfg: ModelConfig, inputs: jax.Array, fit: RowFit,
-                   rows: int) -> Tuple[jax.Array, jax.Array]:
+                   rows: int):
     """``forward_hidden`` over ``inputs`` [n, S] packed into ``rows`` rows
-    as ``fit`` places them, unpacked to [n, S, d]. A position past its
-    sequence's need takes a zero hidden state: no loss reads it. The final
-    norm runs on the packed rows; it is per position, so unpacking after it
-    changes nothing."""
+    as ``fit`` places them, unpacked to [n, S, d], with the aux loss and
+    the expert counters. A position past its sequence's need takes a zero
+    hidden state: no loss reads it. The final norm runs on the packed
+    rows; it is per position, so unpacking after it changes nothing."""
     S = inputs.shape[1]
     src, dest = pack_index(fit, S, rows)
     src = constrain(src, "batch", None)
     ok = src >= 0
     packed = jnp.where(ok, jnp.take(inputs.reshape(-1), jnp.maximum(src, 0)),
                        PAD)
-    h, aux = M.forward_hidden(params, cfg, packed,
-                              positions=jnp.where(ok, src % S, 0),
-                              segment_ids=jnp.where(ok, src // S, -1))
+    h, aux, stats = M.forward_hidden(params, cfg, packed,
+                                     positions=jnp.where(ok, src % S, 0),
+                                     segment_ids=jnp.where(ok, src // S, -1),
+                                     with_stats=True)
     h = jnp.take(h.reshape(rows * S, -1), jnp.minimum(dest, rows * S - 1),
                  axis=0)
     h = jnp.where((dest < rows * S)[..., None], h, 0)
-    return constrain(h, "batch", None, "act_embed"), aux
+    return constrain(h, "batch", None, "act_embed"), aux, stats
 
 
 def _by_rows(fn, n: int, block: Optional[int], fit: Optional[RowFit],
@@ -264,20 +267,25 @@ def _by_rows(fn, n: int, block: Optional[int], fit: Optional[RowFit],
 
 # --------------------------------------------------------------------- score
 def _score_tokens(params, cfg: ModelConfig, tokens: jax.Array,
-                  fit: Optional[RowFit] = None, rows: Optional[int] = None
-                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Per-position logp and entropy of ``tokens``; the decoder on the
-    padded rows, or on ``rows`` packed rows as ``fit`` places them."""
+                  fit: Optional[RowFit] = None, rows: Optional[int] = None,
+                  valid: Optional[jax.Array] = None):
+    """Per-position logp and entropy of ``tokens``, the aux loss and the
+    expert counters (``moe.STATS``; none without experts); the decoder on
+    the padded rows, or on ``rows`` packed rows as ``fit`` places them.
+    On padded rows ``valid`` [B, T-1] marks the positions routed to
+    experts (all where None); packed rows route their sequences' own."""
     tokens = constrain(tokens, "batch", None)
     if rows is None:
-        hidden, aux = M.forward_hidden(params, cfg, tokens[:, :-1])
+        hidden, aux, stats = M.forward_hidden(
+            params, cfg, tokens[:, :-1], pad_mask=valid, with_stats=True)
     else:
-        hidden, aux = _packed_hidden(params, cfg, tokens[:, :-1], fit, rows)
+        hidden, aux, stats = _packed_hidden(params, cfg, tokens[:, :-1], fit,
+                                            rows)
     w = output_head_weight(params["embedding"], cfg)
     with jax.named_scope("logprob_head"):
         logp, entropy = token_logprob_entropy(hidden, w, tokens[:, 1:])
     return (constrain(logp, "batch", None), constrain(entropy, "batch", None),
-            aux)
+            aux, stats)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -289,7 +297,7 @@ def score_tokens(params, cfg: ModelConfig, tokens: jax.Array
     materialize (this is exactly the computation the 'recompute' baseline
     pays for every training step).
     """
-    return _score_tokens(params, cfg, tokens)
+    return _score_tokens(params, cfg, tokens)[:3]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -321,10 +329,24 @@ METRIC_KEYS: Tuple[str, ...] = (
 )
 
 
+def metric_keys(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The step's metric array: ``METRIC_KEYS``, then for an expert stack
+    the expert counters of the step's forward passes, ``moe_local_rows``
+    (assignments computed by the experts held here, over every layer and
+    minibatch) and ``moe_expert_rows_max`` (the most rows any held expert
+    got in one minibatch of one layer)."""
+    return METRIC_KEYS + (MOE_STATS if cfg.moe is not None else ())
+
+
 def _reduce_metrics(stacked: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
     """Fold [n]-stacked per-minibatch metrics: means, except extremes/sums
     (exactly the seed loop-trainer's host-side aggregation, on device)."""
     out = {k: jnp.mean(v, axis=0) for k, v in stacked.items()}
+    if "moe_local_rows" in stacked:
+        out["moe_local_rows"] = jnp.sum(stacked["moe_local_rows"], axis=0)
+    if "moe_expert_rows_max" in stacked:
+        out["moe_expert_rows_max"] = jnp.max(stacked["moe_expert_rows_max"],
+                                             axis=0)
     if "iw_max" in stacked:
         out["iw_max"] = jnp.max(stacked["iw_max"], axis=0)
     if "iw_min" in stacked:
@@ -403,14 +425,20 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
 
     def loss_fn(p, t, fit, rows):
         t = _constrain_batch(t)
-        logp, entropy, aux = _score_tokens(p, cfg, t["tokens"], fit, rows)
+        valid = None
+        if cfg.moe is not None:
+            # the positions packing keeps: up to each sequence's need
+            valid = (jnp.arange(t["mask"].shape[-1], dtype=jnp.int32)
+                     < input_needs(t["mask"])[:, None])
+        logp, entropy, aux, stats = _score_tokens(p, cfg, t["tokens"], fit,
+                                                  rows, valid)
         with jax.named_scope("policy_loss"):
             loss, metrics = algo.loss(logp, LossInputs(
                 advantages=t["advantages"], mask=t["mask"],
                 behav_logp=t.get("behav_logp"), versions=t.get("versions"),
                 current_version=version, prox_logp=t.get("prox"),
                 entropy=entropy), rl)
-        return loss + aux, metrics
+        return loss + aux, dict(metrics, **stats)
 
     def value_and_grads(p, t, fit, rows):
         return jax.value_and_grad(functools.partial(loss_fn, rows=rows),
@@ -480,8 +508,9 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
     out["packed_rows"] = (
         jnp.float32(nmb * mb_size) if block is None
         else jnp.sum(fit.blocks * block).astype(jnp.float32))
-    assert set(out) == set(METRIC_KEYS), sorted(out)
-    packed = jnp.stack([out[k].astype(jnp.float32) for k in METRIC_KEYS])
+    keys = metric_keys(cfg)
+    assert set(out) == set(keys), sorted(out)
+    packed = jnp.stack([out[k].astype(jnp.float32) for k in keys])
     return params, opt, packed
 
 
@@ -607,14 +636,16 @@ class Trainer:
             with span("train_fetch"):
                 values = jax.device_get(packed)
             host_syncs += 1
-            out = {k: float(v) for k, v in zip(METRIC_KEYS, values)}
+            out = {k: float(v)
+                   for k, v in zip(metric_keys(self.cfg), values)}
             out["prox_time_s"] = prox_time
             out["host_syncs"] = float(host_syncs)
             # the padded rows the decoder's packed rows stand for, and the
             # rows it ran per unit (minibatch, or microbatch of one)
             units = nmb * self.num_microbatches
             out["padded_rows"] = float(B // nmb * nmb)
-            update_span.set(rows=out["packed_rows"] / units)
+            update_span.set(rows=out["packed_rows"] / units,
+                            **{k: out[k] for k in MOE_STATS if k in out})
             self.last_host_syncs = host_syncs
             with span("train_metrics"):
                 self._publish_metrics(out)
@@ -628,7 +659,7 @@ class Trainer:
     _GAUGE_KEYS = ("loss", "reward_mean", "entropy", "grad_norm",
                    "iw_max", "iw_min", "iw_mean", "kl", "clipped_frac",
                    "ratio_mean", "staleness_mean", "prox_time_s",
-                   "packed_rows", "padded_rows")
+                   "packed_rows", "padded_rows") + MOE_STATS
     _COUNTER_KEYS = ("tokens", "clipped_tokens", "host_syncs", "nonfinite")
 
     def _publish_metrics(self, out: Dict[str, float]) -> None:
@@ -648,7 +679,7 @@ def sft_update(cfg: ModelConfig, params, opt, tokens, mask, lr: float = 1e-3):
     rl = RLConfig(learning_rate=lr, max_grad_norm=1.0)
 
     def loss_fn(p):
-        logp, _, aux = _score_tokens(p, cfg, tokens)
+        logp, _, aux, _ = _score_tokens(p, cfg, tokens)
         ce = -jnp.sum(logp * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         return ce + aux
 

@@ -1,5 +1,7 @@
-"""Expert-parallel shard_map MoE vs the GSPMD oracle on a real multi-device
-mesh (subprocess: needs XLA_FLAGS device-count override before jax init)."""
+"""Expert-parallel shard_map MoE vs the drop-free local layer on a real
+multi-device mesh (subprocess: needs XLA_FLAGS device-count override before
+jax init). The reduced config's capacity factor (4.0) is one at which the
+EP buckets drop nothing, so both give the whole layer."""
 import os
 import subprocess
 import sys
@@ -24,9 +26,12 @@ SCRIPT = textwrap.dedent("""
                              jnp.float32)
     B, S, d = 4, 20, cfg.d_model   # S=20 exercises the seq-padding path
     x = jax.random.normal(jax.random.PRNGKey(1), (B, S, d)) * 0.5
-    y_ref, _ = moe_mod.moe_apply_gspmd(params, x, cfg)
+    y_ref, _, st_ref = moe_mod.moe_apply_local(params, x, cfg)
     with mesh, use_sharding(env):
-        y_ep, _ = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, cfg))(params, x)
+        y_ep, _, st_ep = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, cfg))(
+            params, x)
+    assert int(st_ref["moe_local_rows"]) == B * S * cfg.moe.top_k
+    assert int(st_ep["moe_local_rows"]) == B * S * cfg.moe.top_k
     err = float(jnp.abs(y_ref - y_ep).max() / (jnp.abs(y_ref).max() + 1e-9))
     assert err < 2e-3, f"EP mismatch: {err}"
     print("EP_OK", err)
@@ -34,6 +39,7 @@ SCRIPT = textwrap.dedent("""
 
 
 def test_ep_dispatch_matches_gspmd_on_8_devices():
+    """The oracle is the drop-free local layer (``moe_apply_local``)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,

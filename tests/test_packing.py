@@ -198,8 +198,8 @@ def test_packed_scoring_matches_padded_at_every_real_position(toy):
     mask, _ = needs_mask(lengths, 1, T)
     fit = RowFit(*(a[0] for a in fit_rows(mask, row_block(8))))
     assert int(fit.blocks) * row_block(8) == 6
-    padded, ent_pad, _ = _score_tokens(params, toy, tokens)
-    packed, ent_pack, _ = _score_tokens(params, toy, tokens, fit, rows=6)
+    padded, ent_pad, _, _ = _score_tokens(params, toy, tokens)
+    packed, ent_pack, _, _ = _score_tokens(params, toy, tokens, fit, rows=6)
     real = np.arange(T - 1)[None, :] < (lengths - 1)[:, None]
     np.testing.assert_allclose(np.asarray(packed)[real],
                                np.asarray(padded)[real], rtol=1e-5,
@@ -214,9 +214,12 @@ def test_packed_scoring_matches_padded_at_every_real_position(toy):
     ["toy-2m", "mamba2-370m", "zamba2-1.2b", "qwen3-moe-30b-a3b",
      "deepseek-v2-lite-16b", "llava-next-mistral-7b"]))
 def test_only_a_plain_attention_stack_packs(arch):
+    # attention stacks pack, plain or latent, dense or with drop-free
+    # routed experts; SSM state and frontend prefixes do not
+    packs = ("toy-2m", "qwen3-moe-30b-a3b", "deepseek-v2-lite-16b")
     cfg = get_config(arch)
-    assert M.packable(cfg) == (arch == "toy-2m")
-    if arch != "toy-2m":
+    assert M.packable(cfg) == (arch in packs)
+    if arch not in packs:
         with pytest.raises(ValueError, match="cannot share a row"):
             M.forward_hidden({}, cfg, jnp.zeros((1, 4), jnp.int32),
                              segment_ids=jnp.zeros((1, 4), jnp.int32))
@@ -236,7 +239,7 @@ def ragged_batch(lengths, T, seed=0):
 
 
 @pytest.mark.parametrize("arch", ["mamba2-370m-reduced",
-                                  "qwen3-moe-30b-a3b-reduced"])
+                                  "zamba2-1.2b-reduced"])
 def test_unpackable_stack_keeps_the_padded_rows(arch):
     cfg = dataclasses.replace(get_config(arch), dtype="float32")
     tr = Trainer(cfg, RLConfig(group_size=4, num_minibatches=2))

@@ -116,3 +116,30 @@ def test_paged_prefill_compiles(sds, widths):
     rows = sds((CHUNK,), jnp.int32)
     _compile(paged_prefill_attention_pallas, q, pool, pool, tables, rows,
              rows, sds((SLOTS,), jnp.int32))
+
+
+def test_drop_free_expert_layer_compiles(sds, monkeypatch):
+    """DeepSeek-V2-Lite's expert layer over the 8 experts held on one chip
+    of expert parallelism 8 (router of 64, top-6, width 1408), forward and
+    backward over one packed minibatch of the train cell (8 rows of 1343):
+    the grouped matmuls compile to the megablox kernel, as on one chip
+    (the default backend here is the CPU, so the test picks the kernel)."""
+    import dataclasses
+
+    from repro.configs.registry import get_config
+    from repro.models import moe as moe_mod
+
+    monkeypatch.setattr(moe_mod, "_use_kernel", lambda: True)
+    full = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(full, moe=dataclasses.replace(full.moe,
+                                                            held=(0, 8)))
+    p = jax.tree.map(lambda s: sds(s.shape, jnp.bfloat16),
+                     moe_mod.moe_spec(cfg),
+                     is_leaf=lambda x: hasattr(x, "logical"))
+    x = sds((8, 1343, cfg.d_model), jnp.bfloat16)
+
+    def loss(p, x):
+        y, aux, _ = moe_mod.moe_apply_local(p, x, cfg)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    _compile(jax.grad(loss, argnums=(0, 1)), p, x)

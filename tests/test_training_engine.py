@@ -91,7 +91,7 @@ def reference_loop_step(cfg, rl, method, state, batch):
         sl = slice(i * mb, (i + 1) * mb)
 
         def loss_fn(p):
-            logp, entropy, aux = _score_tokens(p, cfg, batch.tokens[sl])
+            logp, entropy, aux, _ = _score_tokens(p, cfg, batch.tokens[sl])
             behav, adv = batch.behav_logp[sl], advantages[sl]
             mask = batch.response_mask[sl]
             if method == "sync":
