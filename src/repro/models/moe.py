@@ -5,12 +5,15 @@ Two dispatch paths:
 * ``moe_apply_local`` — the layer over the experts held here
   (``MoEConfig.held``): every real token is routed over all of the
   router's outputs, the assignments to held experts are sorted by expert
-  and run as grouped matmuls (``jax.lax.ragged_dot``) whose work follows
-  the rows routed here, and the results are scattered back weighted. No
-  token is dropped: the sorted buffer is sized for the worst case,
-  ``T * min(top_k, held)`` rows. The experts held elsewhere are left out,
-  so a share of the experts gives its part of the layer's result, as one
-  rank of expert parallelism does before its exchange.
+  and run as grouped matmuls (``grouped_matmul``: the megablox kernel on
+  one TPU chip, ``jax.lax.ragged_dot`` elsewhere) whose work follows the
+  rows routed here, and the results are scattered back weighted. The
+  sorted buffer takes the smaller of two static sizes (``buffer_rungs``)
+  that holds the call's routed rows: the held experts' balanced share,
+  or the worst case, ``T * min(top_k, held)`` rows, so no token is
+  dropped. The experts held elsewhere are left out, so a share of the
+  experts gives its part of the layer's result, as one rank of expert
+  parallelism does before its exchange.
 * ``moe_apply_ep`` — explicit expert-parallel shard_map: tokens are
   seq-sharded over the "model" axis, routed pairs are bucketed by
   destination rank and exchanged with ``jax.lax.all_to_all``, experts
@@ -20,11 +23,13 @@ Two dispatch paths:
 ``moe_apply`` picks EP when the active ShardingEnv requests it, the layer
 holds every expert and the shapes allow (seq at least the model axis),
 else the local path. Both return ``(y, aux, stats)``: ``stats`` counts the
-assignments computed (``moe_local_rows``) and the most any one expert got
-(``moe_expert_rows_max``).
+assignments computed (``moe_local_rows``), the most any one expert got
+(``moe_expert_rows_max``) and the rows of the buffer the experts ran on
+(``moe_buffer_rows``).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -38,7 +43,7 @@ from repro.distributed.sharding import current_env
 from repro.models.layers import swiglu, swiglu_spec
 from repro.models.params import ParamSpec
 
-STATS = ("moe_local_rows", "moe_expert_rows_max")
+STATS = ("moe_local_rows", "moe_expert_rows_max", "moe_buffer_rows")
 # megablox row tile, and the contraction and output tile, capped at the
 # widths (the kernel masks a ragged last tile; its backward swaps the two)
 GMM_ROWS, GMM_COLS = 512, 1024
@@ -136,15 +141,63 @@ def moe_apply(params, x: jax.Array, cfg: ModelConfig,
     return moe_apply_local(params, x, cfg, valid)
 
 
+def buffer_rungs(T: int, m: MoEConfig) -> Tuple[int, ...]:
+    """The dispatch buffer's sizes for ``T`` tokens, smallest first: the
+    held experts' balanced share of the assignments, ``ceil(T * top_k *
+    held / num_experts)``, and the worst case ``T * min(top_k, held)``,
+    each rounded up to ``GMM_ROWS`` (so the grouped matmul pads nothing).
+    A layer holding every expert has its share at the worst case: one
+    rung."""
+    def up(r: int) -> int:
+        return -(-r // GMM_ROWS) * GMM_ROWS
+
+    worst = up(T * min(m.top_k, m.num_held))
+    share = up(-(-T * m.top_k * m.num_held // m.num_experts))
+    return tuple(sorted({min(share, worst), worst}))
+
+
+def _dispatch(xf, top_w, order, sizes, routed, w_gate, w_up, w_down, *,
+              rows: int, k: int) -> jax.Array:
+    """The routed assignments through the held experts on a buffer of
+    ``rows`` rows (``order``'s first, sorted by expert; those past
+    ``routed`` are masked), weighted and added back to [T, d]."""
+    T, d = xf.shape
+    with jax.named_scope("moe_route"):
+        order = order[:rows]
+        live = jnp.arange(rows) < routed
+        tok = order // k
+        xs = jnp.where(live[:, None], jnp.take(xf, tok, axis=0), 0)
+        w = jnp.where(live, top_w[order], 0.0).astype(xf.dtype)
+
+    with jax.named_scope("moe_experts"):
+        g = grouped_matmul(xs, w_gate, sizes)
+        u = grouped_matmul(xs, w_up, sizes)
+        ys = grouped_matmul(jax.nn.silu(g) * u, w_down, sizes)
+
+    with jax.named_scope("moe_combine"):
+        # rows past the routed ones are never computed: mask, not multiply
+        ys = jnp.where(live[:, None], ys, 0) * w[:, None]
+        return jnp.zeros((T, d), xf.dtype).at[tok].add(ys.astype(xf.dtype))
+
+
 def moe_apply_local(params, x: jax.Array, cfg: ModelConfig,
                     valid: Optional[jax.Array] = None
                     ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
-    """The layer over the experts held here, drop-free. x: [B, S, d]."""
+    """The layer over the experts held here, drop-free. x: [B, S, d].
+
+    The assignments to held experts, sorted by expert, fill a buffer of
+    the smaller of ``buffer_rungs(B * S, cfg.moe)`` that holds the
+    call's routed rows, picked by a ``lax.switch`` with one branch a rung,
+    each branch checkpointed (its backward recomputes its forward, as the
+    decoder's checkpointed layer would). The worst case is always a rung,
+    so no assignment is dropped; every rung computes the same rows in the
+    same order. ``stats`` counts the rung's rows (``moe_buffer_rows``)
+    beside the routed ones (``moe_local_rows``)."""
     m = cfg.moe
     B, S, d = x.shape
     T, k = B * S, m.top_k
     first, n_here = m.held_range
-    rows = T * min(k, n_here)  # the most assignments that can land here
+    rungs = buffer_rungs(T, m)
     xf = x.reshape(T, d)
     ok = None if valid is None else valid.reshape(T)
 
@@ -155,30 +208,35 @@ def moe_apply_local(params, x: jax.Array, cfg: ModelConfig,
         mine = (local >= 0) & (local < n_here)
         if ok is not None:
             mine &= ok[:, None]
-        # assignments sorted by local expert; the rest (key n_here) last
+        # assignments sorted by local expert, the rest (key n_here) last;
+        # the largest rung's rounding pads past the T * k of them with
+        # rows that are never live
         key = jnp.where(mine, local, n_here).reshape(T * k)
-        order = jnp.argsort(key, stable=True)[:rows]
+        order = jnp.pad(jnp.argsort(key, stable=True),
+                        (0, max(rungs[-1] - T * k, 0)))
         sizes = jnp.zeros((n_here + 1,), jnp.int32).at[key].add(1)[:n_here]
-        live = jnp.arange(rows) < jnp.sum(sizes)
-        tok = order // k
-        xs = jnp.where(live[:, None], jnp.take(xf, tok, axis=0), 0)
-        w = jnp.where(live, top_w.reshape(T * k)[order], 0.0).astype(x.dtype)
+        routed = jnp.sum(sizes)
+        # the smallest rung that holds every routed row
+        pick = jnp.sum(routed > jnp.asarray(rungs[:-1], jnp.int32))
 
-    with jax.named_scope("moe_experts"):
-        g = grouped_matmul(xs, params["w_gate"], sizes)
-        u = grouped_matmul(xs, params["w_up"], sizes)
-        ys = grouped_matmul(jax.nn.silu(g) * u, params["w_down"], sizes)
-
-    with jax.named_scope("moe_combine"):
-        # rows past the routed ones are never computed: mask, not multiply
-        ys = jnp.where(live[:, None], ys, 0) * w[:, None]
-        y = jnp.zeros((T, d), x.dtype).at[tok].add(ys.astype(x.dtype))
+    operands = (xf, top_w.reshape(T * k), order, sizes, routed,
+                params["w_gate"], params["w_up"], params["w_down"])
+    if len(rungs) == 1:
+        y = _dispatch(*operands, rows=rungs[0], k=k)
+    else:
+        # each branch checkpointed, so that its residuals are its operands,
+        # which the branches share: a switch's backward keeps every
+        # branch's own residuals, zero-filled by the branches not taken
+        y = jax.lax.switch(pick, [
+            jax.checkpoint(functools.partial(_dispatch, rows=r, k=k))
+            for r in rungs], *operands)
 
     if m.num_shared_experts > 0:
         with jax.named_scope("moe_shared"):
             y = y + swiglu(params["shared"], xf)
-    stats = {"moe_local_rows": jnp.sum(sizes),
-             "moe_expert_rows_max": jnp.max(sizes)}
+    stats = {"moe_local_rows": routed,
+             "moe_expert_rows_max": jnp.max(sizes),
+             "moe_buffer_rows": jnp.asarray(rungs, jnp.int32)[pick]}
     return y.reshape(B, S, d), aux, stats
 
 
@@ -205,7 +263,9 @@ def _bucket_by(ids: jax.Array, values: jax.Array, n_buckets: int,
 def _ep_body(x, router_w, w_gate, w_up, w_down, *, m: MoEConfig,
              n_ranks: int, all_axes):
     """Per-device expert-parallel MoE. x: [T_loc, d] (unique local tokens);
-    w_*: this rank's expert slab [E/n_ranks, ...]."""
+    w_*: this rank's expert slab [E/n_ranks, ...]. Returns (y, aux, the
+    assignments to each expert, the experts' buffer rows on every device
+    together)."""
     T, d = x.shape
     e_per = m.num_experts // n_ranks
     k = m.top_k
@@ -266,7 +326,8 @@ def _ep_body(x, router_w, w_gate, w_up, w_down, *, m: MoEConfig,
     inv = jnp.argsort(order, stable=True)
     y_pairs = y_pairs_sorted[inv] * flat_w[:, None]
     y = jnp.zeros((T, d), x.dtype).at[flat_t].add(y_pairs)
-    return y, aux, counts
+    rows = jax.lax.psum(jnp.int32(e_per * C2), all_axes)
+    return y, aux, counts, rows
 
 
 def moe_apply_ep(params, x: jax.Array, cfg: ModelConfig, env
@@ -290,24 +351,24 @@ def moe_apply_ep(params, x: jax.Array, cfg: ModelConfig, env
 
     def body(x_blk, router_w, w_gate, w_up, w_down):
         T = x_blk.shape[0] * x_blk.shape[1]
-        y, aux, counts = _ep_body(x_blk.reshape(T, d), router_w, w_gate,
-                                  w_up, w_down, m=m, n_ranks=n_ranks,
-                                  all_axes=all_axes)
-        return y.reshape(x_blk.shape), aux, counts
+        y, aux, counts, rows = _ep_body(
+            x_blk.reshape(T, d), router_w, w_gate, w_up, w_down, m=m,
+            n_ranks=n_ranks, all_axes=all_axes)
+        return y.reshape(x_blk.shape), aux, counts, rows
 
     x_spec = P(batch_axes if batch_axes else None, "model", None)
     mapped = shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(), P("model"), P("model"), P("model")),
-        out_specs=(x_spec, P(), P()),
+        out_specs=(x_spec, P(), P(), P()),
         check_rep=False)
-    y, aux, counts = mapped(x, params["router"], params["w_gate"], params["w_up"],
-                    params["w_down"])
+    y, aux, counts, rows = mapped(x, params["router"], params["w_gate"],
+                                  params["w_up"], params["w_down"])
     if m.num_shared_experts > 0:
         y = y + swiglu(params["shared"], x.reshape(B * S, d)
                        ).reshape(B, S, d)
     if S != orig_S:
         y = y[:, :orig_S]
     stats = {"moe_local_rows": jnp.sum(counts),
-             "moe_expert_rows_max": jnp.max(counts)}
+             "moe_expert_rows_max": jnp.max(counts), "moe_buffer_rows": rows}
     return y, aux, stats

@@ -333,8 +333,9 @@ def metric_keys(cfg: ModelConfig) -> Tuple[str, ...]:
     """The step's metric array: ``METRIC_KEYS``, then for an expert stack
     the expert counters of the step's forward passes, ``moe_local_rows``
     (assignments computed by the experts held here, over every layer and
-    minibatch) and ``moe_expert_rows_max`` (the most rows any held expert
-    got in one minibatch of one layer)."""
+    minibatch), ``moe_expert_rows_max`` (the most rows any held expert
+    got in one minibatch of one layer) and ``moe_buffer_rows`` (the rows
+    of the dispatch buffers they ran on, summed as ``moe_local_rows``)."""
     return METRIC_KEYS + (MOE_STATS if cfg.moe is not None else ())
 
 
@@ -342,8 +343,9 @@ def _reduce_metrics(stacked: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
     """Fold [n]-stacked per-minibatch metrics: means, except extremes/sums
     (exactly the seed loop-trainer's host-side aggregation, on device)."""
     out = {k: jnp.mean(v, axis=0) for k, v in stacked.items()}
-    if "moe_local_rows" in stacked:
-        out["moe_local_rows"] = jnp.sum(stacked["moe_local_rows"], axis=0)
+    for k in ("moe_local_rows", "moe_buffer_rows"):
+        if k in stacked:
+            out[k] = jnp.sum(stacked[k], axis=0)
     if "moe_expert_rows_max" in stacked:
         out["moe_expert_rows_max"] = jnp.max(stacked["moe_expert_rows_max"],
                                              axis=0)

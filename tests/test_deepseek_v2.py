@@ -310,9 +310,13 @@ def test_train_step_matches_the_reference_loss_and_gradients():
     assert reg.gauge("train_moe_local_rows").get() == m["moe_local_rows"]
     assert reg.gauge("train_moe_expert_rows_max").get() \
         == m["moe_expert_rows_max"]
+    # two expert layers, each on a buffer of at least one 512-row rung
+    assert m["moe_buffer_rows"] >= max(m["moe_local_rows"], 2 * 512)
+    assert reg.gauge("train_moe_buffer_rows").get() == m["moe_buffer_rows"]
     ev, = [e for e in tracer.events()
            if e["ph"] == "X" and e["name"] == "train_update"]
     assert ev["args"]["moe_local_rows"] == m["moe_local_rows"]
+    assert ev["args"]["moe_buffer_rows"] == m["moe_buffer_rows"]
     need = input_needs(jnp.asarray(b["response_mask"]))
     model = RD.Model(TINY)
     _, held = RD.Forward(model).hidden(

@@ -32,6 +32,9 @@ SCRIPT = textwrap.dedent("""
             params, x)
     assert int(st_ref["moe_local_rows"]) == B * S * cfg.moe.top_k
     assert int(st_ep["moe_local_rows"]) == B * S * cfg.moe.top_k
+    # the buffers the experts ran on hold every assignment
+    assert int(st_ref["moe_buffer_rows"]) >= B * S * cfg.moe.top_k
+    assert int(st_ep["moe_buffer_rows"]) >= B * S * cfg.moe.top_k
     err = float(jnp.abs(y_ref - y_ep).max() / (jnp.abs(y_ref).max() + 1e-9))
     assert err < 2e-3, f"EP mismatch: {err}"
     print("EP_OK", err)
