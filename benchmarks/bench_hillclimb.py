@@ -1,16 +1,13 @@
 """Reproduce the §Perf hillclimb measurements (EXPERIMENTS.md §4).
 
-Runs baseline + optimized dry-runs for the three chosen pairs and prints
-the before/after roofline terms. Must run in its own process (forces the
-512-device host platform):
+Runs baseline + optimized dry-runs for the two config-level pairs and
+prints the before/after roofline terms. Must run in its own process
+(forces the 512-device host platform):
 
   PYTHONPATH=src:. python -m benchmarks.bench_hillclimb
 
-NOTE: the codeqwen pair's 3.5x win (EXPERIMENTS §4.1) was an activation-
-constraint *code fix* that is now part of the baseline itself, so this
-script shows only the residual hoist_gather delta for that pair; the
-deepseek-coder (kv_seq+tp_fallback) and qwen3-moe (EP dispatch) gains are
-config-level and reproduce here (10.9x / 28.1x on the dominant term).
+The deepseek-coder pair is kv_seq + tp_fallback, the qwen3-moe pair the
+EP dispatch (10.9x / 28.1x on the dominant term when first recorded).
 """
 import os
 os.environ["XLA_FLAGS"] = (
@@ -22,7 +19,6 @@ def main() -> None:
     from repro.launch.dryrun import dryrun_one
 
     pairs = [
-        ("codeqwen1.5-7b", "train_4k", {}, {"hoist_gather": True}),
         ("deepseek-coder-33b", "decode_32k", {},
          {"kv_seq_shard": True, "fsdp": False, "tp_fallback": True}),
         ("qwen3-moe-30b-a3b", "train_4k", {}, {"ep_moe": True}),

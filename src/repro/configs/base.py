@@ -281,13 +281,10 @@ class RLConfig:
     """A-3PO / decoupled-PPO algorithm settings (paper §4.1 defaults).
 
     Algorithm selection lives in ``algo`` (an ``Algorithm`` instance from
-    ``repro.core.algorithms``); the stringly-typed ``method`` field is the
-    deprecated pre-registry spelling, kept as a fallback the registry shim
-    resolves (``resolve_algorithm``).
+    ``repro.core.algorithms``); None means ``a3po``.
     """
 
     algo: Optional[AlgoConfig] = None  # nested per-algorithm config
-    method: str = "loglinear"  # DEPRECATED: a3po/loglinear | recompute | sync
     alpha_schedule: str = "inverse"  # inverse (paper 1/d) | exp | clipped | const
     alpha_const: float = 0.5
     alpha_gamma: float = 0.5  # for exp schedule: alpha = gamma ** d

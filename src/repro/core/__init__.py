@@ -10,14 +10,10 @@ from repro.core.advantages import (  # noqa: F401
     group_normalized_advantages,
 )
 from repro.core.objective import (  # noqa: F401
-    fused_a3po_loss,
-    policy_objective,
-    resolve_alpha,
-)
-from repro.core.losses import (  # noqa: F401
     coupled_ppo_loss,
     decoupled_ppo_loss,
-    policy_loss,
+    fused_a3po_loss,
+    resolve_alpha,
 )
 from repro.core.algorithms import (  # noqa: F401
     Algorithm,

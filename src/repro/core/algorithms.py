@@ -183,8 +183,7 @@ def get_algorithm(name: str, **overrides) -> Algorithm:
 
 def resolve_algorithm(spec=None, rl: Optional[RLConfig] = None) -> Algorithm:
     """The one entry point drivers use: Algorithm instance | registry name
-    | None (falls back to ``rl.algo``, then the deprecated ``rl.method``
-    string, then the paper default ``a3po``)."""
+    | None (falls back to ``rl.algo``, then the paper default ``a3po``)."""
     if isinstance(spec, Algorithm):
         return spec
     if isinstance(spec, str):
@@ -192,11 +191,9 @@ def resolve_algorithm(spec=None, rl: Optional[RLConfig] = None) -> Algorithm:
     if spec is not None:
         raise TypeError(f"algo must be an Algorithm or registry name, "
                         f"got {type(spec).__name__}")
-    if rl is not None:
-        if rl.algo is not None:
-            assert isinstance(rl.algo, Algorithm), rl.algo
-            return rl.algo
-        return get_algorithm(rl.method)
+    if rl is not None and rl.algo is not None:
+        assert isinstance(rl.algo, Algorithm), rl.algo
+        return rl.algo
     return get_algorithm("a3po")
 
 

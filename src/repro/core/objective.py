@@ -1,24 +1,25 @@
 """Unified A-3PO training objective (the hot inner loop of the engine).
 
-One interface for the three methods the paper compares:
+The losses of the three methods the paper compares:
 
 * ``sync``      — coupled PPO/GRPO (Eq. 1): pi_old is IS weight + anchor.
 * ``recompute`` — decoupled PPO (Eq. 2) with an explicitly recomputed
                   proximal anchor (the forward pass A-3PO deletes).
-* ``loglinear`` — A-3PO (Eq. 3-4 / Listing 1): the anchor is a log-linear
+* ``a3po``      — A-3PO (Eq. 3-4 / Listing 1): the anchor is a log-linear
                   interpolation weighted by the staleness-aware alpha.
 
 ``resolve_alpha`` is the single dispatch point for every alpha schedule —
 including the beyond-paper ``kl_adaptive`` controller, which needs the
 live/behavior logps and therefore cannot be computed from staleness alone.
 
-The ``loglinear`` clipped-surrogate inner loop routes through the fused
+The ``a3po`` clipped-surrogate inner loop routes through the fused
 ``kernels/a3po_loss`` Pallas kernel (interpret mode off-TPU) behind a
 ``custom_vjp``: one fused elementwise pass computes loss, clip indicators,
 importance weights, and trust-region ratios; the backward pass is the
 analytic gradient, with the pure-jnp ref as the oracle. Alpha is computed
 from the ``[B]`` or ``[B, T]`` version stamps and broadcast into the fused
-path. ``core.losses`` is a thin compatibility layer over this module.
+path. ``core.algorithms`` wraps these losses as registered ``Algorithm``
+objects, the one way a caller names an objective.
 """
 from __future__ import annotations
 
@@ -223,52 +224,3 @@ def fused_a3po_loss(
     anchor = alpha * behav_logp + (1.0 - alpha) * logp
     return apply_regularizers(loss, metrics, logp, anchor, mask, cfg,
                               entropy)
-
-
-# ------------------------------------------------------------------- dispatch
-def policy_objective(
-    algo=None,
-    logp: Optional[jax.Array] = None,
-    behav_logp: Optional[jax.Array] = None,
-    advantages: Optional[jax.Array] = None,
-    mask: Optional[jax.Array] = None,
-    cfg: Optional[RLConfig] = None,
-    *,
-    versions: Optional[jax.Array] = None,
-    current_version=None,
-    recomputed_prox_logp: Optional[jax.Array] = None,
-    entropy: Optional[jax.Array] = None,
-    method: Optional[str] = None,
-) -> Tuple[jax.Array, Metrics]:
-    """Unified objective, dispatched through the Algorithm registry.
-
-    ``algo`` is an ``Algorithm`` instance (``repro.core.algorithms``) or a
-    registry name. Stringly-typed dispatch — a name positionally or the
-    legacy ``method=`` keyword — still resolves through the registry but
-    emits a ``DeprecationWarning``; new call sites should pass
-    ``get_algorithm("a3po")`` (or any registered Algorithm) directly.
-    """
-    import warnings
-
-    from repro.core.algorithms import Algorithm, LossInputs, get_algorithm
-
-    if method is not None:
-        warnings.warn(
-            "policy_objective(method=...) is deprecated; pass an Algorithm "
-            "from repro.core.algorithms (e.g. get_algorithm('a3po'))",
-            DeprecationWarning, stacklevel=2)
-        if algo is None:
-            algo = method
-    if isinstance(algo, str):
-        if method is None:
-            warnings.warn(
-                f"stringly-typed policy_objective({algo!r}, ...) is "
-                "deprecated; pass an Algorithm from repro.core.algorithms",
-                DeprecationWarning, stacklevel=2)
-        algo = get_algorithm(algo)
-    assert isinstance(algo, Algorithm), algo
-    batch = LossInputs(
-        behav_logp=behav_logp, advantages=advantages, mask=mask,
-        versions=versions, current_version=current_version,
-        prox_logp=recomputed_prox_logp, entropy=entropy)
-    return algo.loss(logp, batch, cfg or RLConfig())

@@ -32,6 +32,7 @@ from repro.launch.mesh import (  # noqa: E402
 from repro.launch import steps  # noqa: E402
 from repro.models import model as M  # noqa: E402
 from repro.obs.runlog import RunLogger  # noqa: E402
+from repro.training.trainer import check_trainable  # noqa: E402
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "experiments", "dryrun")
@@ -52,14 +53,29 @@ def _mem_dict(mem) -> dict:
 def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
                algo="a3po", fsdp: bool = True,
                save: bool = True, verbose: bool = True,
-               rules=None, hoist_gather: bool = False,
-               kv_seq_shard: bool = False, zero1: bool = False,
+               rules=None, kv_seq_shard: bool = False,
                tp_fallback: bool = False, ep_moe: bool = False,
-               num_microbatches: int = 8, prefill_microbatches: int = 1,
+               num_microbatches: int = 1, prefill_microbatches: int = 1,
                tag_suffix: str = "", run_logger: RunLogger = None) -> dict:
+    """Lower and compile one (arch x shape) on the production mesh. A train
+    shape lowers ``Trainer.step``'s own compiled step
+    (``steps.lower_train_step``); a stack the Trainer refuses (a frontend
+    stack) is skipped with the reason, as ``{"skipped": reason}``."""
     from repro.core.algorithms import resolve_algorithm
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
+    if shape.kind == "train":
+        try:
+            check_trainable(cfg)
+        except ValueError as e:
+            if verbose:
+                line = f"[dryrun] {arch} x {shape_name}: skipped ({e})"
+                if run_logger is not None:
+                    run_logger.print(line)
+                else:
+                    print(line, flush=True)
+            return {"arch": arch, "shape": shape_name, "kind": shape.kind,
+                    "skipped": str(e)}
     rl = RLConfig()
     algo = resolve_algorithm(algo, rl)
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -77,46 +93,28 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
                             tp_fallback=tp_fallback))
     env.ep_shard_map = ep_moe
 
-    specs = steps.input_specs(cfg, shape)
-    if shape.kind == "train":
-        step = steps.make_train_step(cfg, rl, algo,
-                                     num_microbatches=num_microbatches,
-                                     hoist_fsdp_gather=hoist_gather)
-    elif shape.kind == "prefill" and prefill_microbatches > 1:
-        step = steps.make_prefill_step(cfg, shape, prefill_microbatches)
-    else:
-        step = steps.make_step(cfg, shape, rl, algo)
-    params_abs = M.abstract_params(cfg)
-    param_sh = M.param_shardings(cfg, env)
-    batch_sh = steps.batch_shardings(cfg, shape, env, specs)
-    opt_env = env
-    if zero1:
-        # §Perf lever (ZeRO-1): weights replicated across data (TP only),
-        # optimizer moments FSDP-sharded. Kills the pathological
-        # activation all-gathers XLA emits for FSDP weight gradients.
-        env = ShardingEnv(mesh, rules=tuple(env.rules.items()), fsdp=False,
-                          tp_fallback=tp_fallback)
-        param_sh = M.param_shardings(cfg, env)
-
     t0 = time.time()
-    with mesh, use_sharding(env):
-        if shape.kind == "train":
-            opt_abs = steps.abstract_opt_state(params_abs)
-            opt_sh = steps.opt_shardings(
-                M.param_shardings(cfg, opt_env) if zero1 else param_sh, env)
-            jitted = jax.jit(step, in_shardings=(param_sh, opt_sh, batch_sh))
-            lowered = jitted.lower(params_abs, opt_abs, specs)
-        elif shape.kind == "decode":
-            # donate the KV/SSM cache: serving aliases it in place
-            jitted = jax.jit(step, in_shardings=(param_sh, batch_sh),
-                             donate_argnums=(1,))
-            lowered = jitted.lower(params_abs, specs)
+    if shape.kind == "train":
+        lowered = steps.lower_train_step(
+            cfg, rl, algo, env, shape.global_batch, shape.seq_len,
+            num_microbatches)
+    else:
+        specs = steps.input_specs(cfg, shape)
+        if shape.kind == "prefill" and prefill_microbatches > 1:
+            step = steps.make_prefill_step(cfg, shape, prefill_microbatches)
         else:
-            jitted = jax.jit(step, in_shardings=(param_sh, batch_sh))
-            lowered = jitted.lower(params_abs, specs)
-        t_lower = time.time() - t0
-        compiled = lowered.compile()
-        t_compile = time.time() - t0 - t_lower
+            step = steps.make_step(cfg, shape, rl, algo)
+        params_abs = M.abstract_params(cfg)
+        param_sh = M.param_shardings(cfg, env)
+        batch_sh = steps.batch_shardings(cfg, shape, env, specs)
+        # donate the KV/SSM cache: serving aliases it in place
+        donate = (1,) if shape.kind == "decode" else ()
+        with mesh, use_sharding(env):
+            lowered = jax.jit(step, in_shardings=(param_sh, batch_sh),
+                              donate_argnums=donate).lower(params_abs, specs)
+    t_lower = time.time() - t0
+    compiled = lowered.compile()
+    t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
     # newer jax returns a per-program list of dicts; older a single dict
@@ -212,8 +210,6 @@ def main() -> None:
     p.add_argument("--algo", default=None,
                    help="policy-optimization algorithm (registry name, "
                         "default a3po)")
-    p.add_argument("--method", default=None,
-                   help="DEPRECATED alias for --algo")
     p.add_argument("--no-fsdp", action="store_true")
     # §Perf optimization levers (see EXPERIMENTS.md §4)
     p.add_argument("--ep-moe", action="store_true",
@@ -222,18 +218,12 @@ def main() -> None:
                    help="shard decode KV cache along sequence")
     p.add_argument("--tp-fallback", action="store_true",
                    help="row-parallel fallback for non-divisible heads")
-    p.add_argument("--hoist-gather", action="store_true",
-                   help="hoist FSDP weight all-gather out of microbatches")
     p.add_argument("--tag", default="", help="suffix for result files")
     p.add_argument("--log-jsonl", default=None, metavar="FILE",
                    help="append one schema-versioned JSONL record per combo")
     p.add_argument("--quiet", action="store_true",
                    help="suppress stdout progress lines (JSONL still logs)")
     args = p.parse_args()
-    if args.method:
-        import warnings
-        warnings.warn("--method is deprecated; use --algo",
-                      DeprecationWarning)
 
     combos = []
     if args.all:
@@ -245,18 +235,18 @@ def main() -> None:
         combos = [(args.arch, args.shape)]
 
     log = RunLogger(args.log_jsonl, quiet=args.quiet)
-    failures = []
+    failures, skipped = [], 0
     try:
         for arch, shape in combos:
             try:
-                dryrun_one(arch, shape, multi_pod=args.multi_pod,
-                           algo=args.algo or args.method or "a3po",
-                           fsdp=not args.no_fsdp,
-                           ep_moe=args.ep_moe,
-                           kv_seq_shard=args.kv_seq_shard,
-                           tp_fallback=args.tp_fallback,
-                           hoist_gather=args.hoist_gather,
-                           tag_suffix=args.tag, run_logger=log)
+                rec = dryrun_one(arch, shape, multi_pod=args.multi_pod,
+                                 algo=args.algo or "a3po",
+                                 fsdp=not args.no_fsdp,
+                                 ep_moe=args.ep_moe,
+                                 kv_seq_shard=args.kv_seq_shard,
+                                 tp_fallback=args.tp_fallback,
+                                 tag_suffix=args.tag, run_logger=log)
+                skipped += "skipped" in rec
             except Exception as e:  # noqa: BLE001
                 failures.append((arch, shape, repr(e)))
                 log.log_event("dryrun_failure", arch=arch, shape=shape,
@@ -267,8 +257,9 @@ def main() -> None:
             for f in failures:
                 log.print(f"   {f}")
             raise SystemExit(1)
-        log.print(f"\nALL {len(combos)} combos compiled OK "
-                  f"({'2x16x16' if args.multi_pod else '16x16'})")
+        log.print(f"\nALL {len(combos) - skipped} combos compiled OK "
+                  f"({'2x16x16' if args.multi_pod else '16x16'}), "
+                  f"{skipped} skipped")
     finally:
         log.close()
 

@@ -1,9 +1,10 @@
 """Jit-able production step functions + abstract input specs.
 
 These are the programs the dry-run lowers for every (arch x shape x mesh)
-combination and that ``launch/train.py`` / ``launch/serve.py`` execute:
+combination:
 
-* ``train_step``   — full A-3PO RL update: score + decoupled loss + bwd + Adam
+* train        — ``training.trainer._train_step``, the RL update
+                 ``Trainer.step`` runs (``lower_train_step``)
 * ``prefill_step`` — prompt ingestion, returns last-token logits + kv cache
 * ``decode_step``  — one token for every sequence against a full cache
 
@@ -19,13 +20,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import InputShape, ModelConfig, RLConfig
-from repro.core.algorithms import LossInputs, resolve_algorithm
-from repro.distributed.sharding import ShardingEnv, current_env
-from repro.kernels.logprob import token_logprob_entropy
+from repro.core.algorithms import resolve_algorithm
+from repro.distributed.sharding import ShardingEnv, use_sharding
 from repro.models import model as M
-from repro.models.layers import logits_from_hidden, output_head_weight
-from repro.models.params import shardings_from_specs
-from repro.training.optimizer import adam_update
+from repro.models.layers import logits_from_hidden
+from repro.training.trainer import _train_step, check_trainable
 
 
 def decode_window(cfg: ModelConfig, shape: InputShape) -> Optional[int]:
@@ -44,88 +43,6 @@ def decode_window(cfg: ModelConfig, shape: InputShape) -> Optional[int]:
 
 
 # ----------------------------------------------------------------- factories
-def _hoisted_gather(params, cfg: ModelConfig):
-    """FSDP all-gather hoisting (§Perf lever): constrain a compute copy of
-    the weights to their non-FSDP sharding OUTSIDE the microbatch scan, so
-    the data-axis all-gather happens once per training step instead of per
-    microbatch x fwd/bwd/remat. Gradients transpose back through the
-    constraint as reduce-scatters onto the FSDP layout."""
-    env = current_env()
-    if env is None:
-        return params
-    gathered_env = ShardingEnv(env.mesh, rules=tuple(env.rules.items()),
-                               fsdp=False)
-    sh = shardings_from_specs(M.model_spec(cfg), gathered_env)
-    return jax.tree.map(jax.lax.with_sharding_constraint, params, sh)
-
-
-def make_train_step(cfg: ModelConfig, rl: RLConfig, algo="a3po",
-                    current_version: int = 4, num_microbatches: int = 8,
-                    hoist_fsdp_gather: bool = False):
-    """Full RL training step over the global batch.
-
-    ``algo`` is an ``Algorithm`` or registry name; its requires-flags
-    decide which batch operands feed the loss (the dry-run stands in
-    ``behav_logp`` for the recomputed prox — same shape/sharding).
-    Gradient-accumulates over ``num_microbatches`` (lax.scan) — the paper
-    bounds minibatches at 10,240 tokens; accumulation keeps activation
-    memory at 1/num_microbatches of the global batch while the HLO stays
-    O(1) in microbatch count."""
-    algo = resolve_algorithm(algo, rl)
-    F = cfg.frontend_tokens if cfg.frontend else 0
-
-    def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        hidden, aux = M.forward_hidden(params, cfg, tokens[:, :-1],
-                                       embeds=batch.get("embeds"))
-        if F:
-            hidden = hidden[:, F:]  # loss only over text positions
-        w = output_head_weight(params["embedding"], cfg)
-        logp, entropy = token_logprob_entropy(hidden, w, tokens[:, 1:])
-        loss, metrics = algo.loss(logp, LossInputs(
-            advantages=batch["advantages"], mask=batch["mask"],
-            behav_logp=batch["behav_logp"], versions=batch["versions"],
-            current_version=current_version,
-            prox_logp=(batch["behav_logp"] if algo.needs_prox_forward
-                       else None),
-            entropy=entropy), rl)
-        return loss + aux, metrics
-
-    def train_step(params, opt, batch):
-        B = batch["tokens"].shape[0]
-        nm = num_microbatches if B % num_microbatches == 0 else 1
-        compute_params = (_hoisted_gather(params, cfg)
-                          if hoist_fsdp_gather else params)
-        if nm == 1:
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(compute_params, batch)
-            entropy = metrics["entropy"]
-        else:
-            mb = {k: v.reshape((nm, B // nm) + v.shape[1:])
-                  for k, v in batch.items()}
-            g0 = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-
-            def accum(carry, micro):
-                g_acc, loss_acc, ent_acc = carry
-                (loss, metrics), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(compute_params, micro)
-                g_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), g_acc, grads)
-                return (g_acc, loss_acc + loss,
-                        ent_acc + metrics["entropy"]), None
-
-            (grads, loss, entropy), _ = jax.lax.scan(
-                accum, (g0, jnp.zeros((), jnp.float32),
-                        jnp.zeros((), jnp.float32)), mb)
-            grads = jax.tree.map(lambda g: g / nm, grads)
-            loss, entropy = loss / nm, entropy / nm
-        params, opt, gnorm = adam_update(grads, opt, params, rl)
-        return params, opt, loss, entropy, gnorm
-
-    return train_step
-
-
 def make_prefill_step(cfg: ModelConfig, shape: InputShape,
                       num_microbatches: int = 1):
     """Prefill the prompt batch; ``num_microbatches`` > 1 scans over batch
@@ -180,11 +97,61 @@ def make_decode_step(cfg: ModelConfig, shape: InputShape):
 
 def make_step(cfg: ModelConfig, shape: InputShape, rl: RLConfig,
               algo="a3po"):
-    if shape.kind == "train":
-        return make_train_step(cfg, rl, algo)
+    """The step of ``shape``'s kind over the batch of ``input_specs``: for
+    a train shape, ``Trainer.step``'s compiled step (``_train_step``) on
+    its operands, returning new params, Adam state and packed metrics."""
     if shape.kind == "prefill":
         return make_prefill_step(cfg, shape)
-    return make_decode_step(cfg, shape)
+    if shape.kind == "decode":
+        return make_decode_step(cfg, shape)
+    algo = resolve_algorithm(algo, rl)
+
+    def train_step(params, opt, batch):
+        args, statics = _train_args(cfg, rl, algo, batch)
+        return _train_step(params, opt, *args, **statics)
+
+    return train_step
+
+
+def _train_args(cfg: ModelConfig, rl: RLConfig, algo, batch: Dict[str, Any],
+                num_microbatches: int = 1):
+    """``_train_step``'s operands after params and opt, and its statics, as
+    ``Trainer.step`` passes them, from a train ``batch`` of ``input_specs``'
+    names; ``behav_logp`` stands in for a ``needs_prox_forward`` prox."""
+    args = (batch["version"], batch["tokens"], batch["behav_logp"],
+            batch["mask"], batch["versions"], batch["rewards"],
+            batch["behav_logp"] if algo.needs_prox_forward else None)
+    statics = dict(cfg=cfg, rl=rl, algo=algo,
+                   num_minibatches=min(rl.num_minibatches,
+                                       batch["tokens"].shape[0]),
+                   num_microbatches=num_microbatches, skip_nonfinite=False)
+    return args, statics
+
+
+def lower_train_step(cfg: ModelConfig, rl: RLConfig, algo, env: ShardingEnv,
+                     batch: int, seq_len: int, num_microbatches: int = 1):
+    """Lower ``training.trainer._train_step``, the jit ``Trainer.step``
+    calls, for ``batch`` sequences of ``seq_len`` tokens, as the Trainer
+    would call it under ``env``: ShapeDtypeStructs carry the state's
+    placements (params by the logical rules, Adam moments beside them in
+    f32) and the batch split over its batch axes. A ``needs_prox_forward``
+    algorithm gets ``behav_logp`` as its stand-in prox (same shape and
+    sharding). Returns the ``Lowered`` step; ``.compile()`` it."""
+    algo = resolve_algorithm(algo, rl)
+    shape = InputShape("train", seq_len, batch, "train")
+    specs = input_specs(cfg, shape)
+    sds = jax.ShapeDtypeStruct
+    params = jax.tree.map(lambda a, sh: sds(a.shape, a.dtype, sharding=sh),
+                          M.abstract_params(cfg), M.param_shardings(cfg, env))
+    moment = jax.tree.map(
+        lambda a: sds(a.shape, jnp.float32, sharding=a.sharding), params)
+    opt = {"m": moment, "v": moment,
+           "t": sds((), jnp.int32, sharding=env.sharding((), ()))}
+    sh = batch_shardings(cfg, shape, env, specs)
+    ops = {k: sds(v.shape, v.dtype, sharding=sh[k]) for k, v in specs.items()}
+    args, statics = _train_args(cfg, rl, algo, ops, num_microbatches)
+    with env.mesh, use_sharding(env):
+        return _train_step.lower(params, opt, *args, **statics)
 
 
 # --------------------------------------------------------------- input specs
@@ -198,16 +165,14 @@ def input_specs(cfg: ModelConfig, shape: InputShape,
     F = cfg.frontend_tokens if cfg.frontend else 0
     specs: Dict[str, Any] = {}
     if shape.kind == "train":
-        # total context = F frontend embeddings + (S - F) text tokens
-        Tt = S - F
-        specs["tokens"] = jax.ShapeDtypeStruct((B, Tt), i32)
-        specs["behav_logp"] = jax.ShapeDtypeStruct((B, Tt - 1), f32)
-        specs["advantages"] = jax.ShapeDtypeStruct((B, Tt - 1), f32)
-        specs["mask"] = jax.ShapeDtypeStruct((B, Tt - 1), f32)
+        # ``Trainer.step``'s operands: the target version and a TrainBatch
+        check_trainable(cfg)
+        specs["version"] = jax.ShapeDtypeStruct((), i32)
+        specs["tokens"] = jax.ShapeDtypeStruct((B, S), i32)
+        specs["behav_logp"] = jax.ShapeDtypeStruct((B, S - 1), f32)
+        specs["mask"] = jax.ShapeDtypeStruct((B, S - 1), f32)
         specs["versions"] = jax.ShapeDtypeStruct((B,), i32)
-        if F:
-            specs["embeds"] = jax.ShapeDtypeStruct((B, F, cfg.d_model),
-                                                   dtype)
+        specs["rewards"] = jax.ShapeDtypeStruct((B,), f32)
     elif shape.kind == "prefill":
         specs["tokens"] = jax.ShapeDtypeStruct((B, S - F), i32)
         if F:
@@ -223,24 +188,6 @@ def input_specs(cfg: ModelConfig, shape: InputShape,
     return specs
 
 
-def abstract_opt_state(params_abstract):
-    """Abstract Adam state matching ``training.optimizer.adam_init``."""
-    f32 = lambda p: jax.ShapeDtypeStruct(p.shape, jnp.float32)  # noqa: E731
-    return {
-        "m": jax.tree.map(f32, params_abstract),
-        "v": jax.tree.map(f32, params_abstract),
-        "t": jax.ShapeDtypeStruct((), jnp.int32),
-    }
-
-
-def opt_shardings(param_sh, env: ShardingEnv):
-    return {
-        "m": param_sh,
-        "v": param_sh,
-        "t": env.sharding((), ()),
-    }
-
-
 def batch_shardings(cfg: ModelConfig, shape: InputShape, env: ShardingEnv,
                     specs: Dict[str, Any]) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
@@ -249,6 +196,8 @@ def batch_shardings(cfg: ModelConfig, shape: InputShape, env: ShardingEnv,
             out["cache"] = M.cache_shardings(cfg, env, spec)
         elif name == "embeds":
             out[name] = env.sharding(spec.shape, ("batch", None, "act_embed"))
+        elif spec.ndim == 0:
+            out[name] = env.sharding((), ())
         elif spec.ndim == 1:
             out[name] = env.sharding(spec.shape, ("batch",))
         else:

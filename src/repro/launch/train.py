@@ -7,9 +7,9 @@ hands the trainer's arrays to the engine in-process (no separate rollout
 slice exists). On a CPU host it runs at toy scale, and
 ``--mesh prod``/``prod-multipod`` dry-runs the compiled training
 engine against the full-scale mesh: params and Adam moments are placed with
-``ShardingEnv``'s logical-axis rules, the scan-based ``train_step`` is
-lowered + compiled with those in_shardings, and the launcher verifies no
-weight matrix is left fully replicated.
+``ShardingEnv``'s logical-axis rules, ``Trainer.step``'s compiled step is
+lowered + compiled with those placements (``steps.lower_train_step``), and
+the launcher verifies no weight matrix is left fully replicated.
 
 Algorithm selection goes through the Algorithm registry
 (``core.algorithms``): ``--algo a3po|recompute|sync|asympo|grpo_mu|...``
@@ -51,11 +51,9 @@ if __name__ == "__main__" and any(
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
-import functools  # noqa: E402
 import time  # noqa: E402
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs.base import RLConfig  # noqa: E402
@@ -78,7 +76,6 @@ from repro.models import model as M  # noqa: E402
 from repro.obs.metrics import get_registry  # noqa: E402
 from repro.obs.runlog import RunLogger  # noqa: E402
 from repro.obs.tracing import SpanTracer, install_tracer  # noqa: E402
-from repro.training import trainer as trainer_mod  # noqa: E402
 from repro.training.checkpoints import save_checkpoint  # noqa: E402
 
 
@@ -96,61 +93,26 @@ def _replicated_weights(sh_tree, abs_tree) -> list:
 def sharded_dryrun(cfg, rl: RLConfig, env: ShardingEnv, algo: Algorithm,
                    batch_size: int = 32, seq_len: int = 14,
                    num_microbatches: int = 1) -> None:
-    """Lower + compile the scan-based training engine on the production
-    mesh with ShardingEnv placements for params, Adam moments, and batch."""
-    params_abs = M.abstract_params(cfg, dtype=jnp.dtype(cfg.dtype))
+    """Lower + compile ``Trainer.step``'s compiled step on the production
+    mesh (``steps.lower_train_step``) with ShardingEnv placements for
+    params, Adam moments, and batch."""
+    params_abs = M.abstract_params(cfg)
     param_sh = M.param_shardings(cfg, env)
-    opt_abs = steps.abstract_opt_state(params_abs)
-    opt_sh = steps.opt_shardings(param_sh, env)
-
     bad = _replicated_weights(param_sh, params_abs)
     assert not bad, f"fully-replicated weight tensors on the mesh: {bad}"
+
+    t0 = time.time()
+    lowered = steps.lower_train_step(cfg, rl, algo, env, batch_size, seq_len,
+                                     num_microbatches)
+    t_lower = time.time() - t0
+    compiled = lowered.compile()
+    t_compile = time.time() - t0 - t_lower
+    (_, opt_sh, *_), _ = compiled.input_shardings
     bad_m = _replicated_weights(opt_sh["m"], params_abs)
     assert not bad_m, f"fully-replicated Adam moments on the mesh: {bad_m}"
     print(f"[sharded] params + Adam moments carry ShardingEnv placements "
           f"({len(jax.tree.leaves(param_sh))} tensors, 0 replicated "
           f"weight matrices)")
-
-    B, T = batch_size, seq_len
-    i32, f32 = jnp.int32, jnp.float32
-    batch_abs = dict(
-        version=jax.ShapeDtypeStruct((), i32),
-        tokens=jax.ShapeDtypeStruct((B, T), i32),
-        behav_logp=jax.ShapeDtypeStruct((B, T - 1), f32),
-        mask=jax.ShapeDtypeStruct((B, T - 1), f32),
-        versions=jax.ShapeDtypeStruct((B,), i32),
-        rewards=jax.ShapeDtypeStruct((B,), f32),
-    )
-    batch_sh = dict(
-        version=env.sharding((), ()),
-        tokens=env.sharding((B, T), ("batch", None)),
-        behav_logp=env.sharding((B, T - 1), ("batch", None)),
-        mask=env.sharding((B, T - 1), ("batch", None)),
-        versions=env.sharding((B,), ("batch",)),
-        rewards=env.sharding((B,), ("batch",)),
-    )
-
-    step = functools.partial(
-        trainer_mod._train_step_impl, cfg=cfg, rl=rl, algo=algo,
-        num_minibatches=rl.num_minibatches,
-        num_microbatches=num_microbatches)
-
-    def wrapped(params, opt, batch):
-        # the dry-run has no real recomputed prox; stand in with behav_logp
-        # (same shape/sharding) so the compiled program is representative
-        prox = batch["behav_logp"] if algo.needs_prox_forward else None
-        return step(params, opt, batch["version"], batch["tokens"],
-                    batch["behav_logp"], batch["mask"], batch["versions"],
-                    batch["rewards"], prox)
-
-    t0 = time.time()
-    with env.mesh, use_sharding(env):
-        jitted = jax.jit(wrapped, in_shardings=(param_sh, opt_sh, batch_sh),
-                         donate_argnums=(1,))
-        lowered = jitted.lower(params_abs, opt_abs, batch_abs)
-        t_lower = time.time() - t0
-        compiled = lowered.compile()
-        t_compile = time.time() - t0 - t_lower
     out_p_sh, _, _ = compiled.output_shardings
     bad_out = [p for (p, sh), leaf in
                zip(jax.tree_util.tree_flatten_with_path(out_p_sh)[0],
@@ -187,8 +149,6 @@ def main() -> None:
                    help="policy-optimization algorithm (registry name, "
                         "default a3po), or 'list' to enumerate the "
                         "registry")
-    p.add_argument("--method", default=None,
-                   help="DEPRECATED alias for --algo")
     p.add_argument("--steps", type=int, default=20)
     p.add_argument("--staleness", type=int, default=2)
     p.add_argument("--mesh", default="local",
@@ -249,12 +209,7 @@ def main() -> None:
         print_algo_list()
         return
     enable_compile_cache()
-    if args.method:
-        import warnings
-        warnings.warn("--method is deprecated; use --algo",
-                      DeprecationWarning)
-    # an explicit --algo always wins over the deprecated --method alias
-    algo = resolve_algorithm(args.algo or args.method or "a3po")
+    algo = resolve_algorithm(args.algo or "a3po")
 
     log = RunLogger(args.log_jsonl, quiet=args.quiet)
     tracer = (install_tracer(SpanTracer(), annotate_jax=True)

@@ -38,6 +38,8 @@ class DivergenceDetector:
     Trips when the newest loss is non-finite, or exceeds
     ``mean + threshold_sigmas * std`` of the trailing window (computed
     *excluding* the newest sample, with at least ``min_window`` history).
+    ``std`` is floored at a hundredth of ``max(1, |mean|)``, so a spike
+    after a flat window still trips.
     """
 
     def __init__(self, window: int = 16, threshold_sigmas: float = 6.0,
@@ -57,9 +59,8 @@ class DivergenceDetector:
         if len(hist) >= self.min_window:
             mean = sum(hist) / len(hist)
             var = sum((x - mean) ** 2 for x in hist) / len(hist)
-            std = math.sqrt(var)
-            if std > 0 and loss > mean + self.threshold_sigmas * std:
-                tripped = True
+            std = max(math.sqrt(var), 0.01 * max(1.0, abs(mean)))
+            tripped = loss > mean + self.threshold_sigmas * std
         if not tripped:
             self._losses.append(loss)
         return tripped

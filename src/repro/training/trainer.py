@@ -2,7 +2,7 @@
 
 Matches the paper's procedure (§4.1): one *training step* consumes a rollout
 batch, optionally recomputes the proximal policy with an extra forward pass
-(method='recompute' — the cost A-3PO deletes), then performs
+(algorithm ``recompute`` — the cost A-3PO deletes), then performs
 ``num_minibatches`` gradient updates with the frozen anchor.
 
 Engine architecture (PR 2): the whole update path is a single jitted
@@ -43,7 +43,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-import warnings
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -516,60 +515,49 @@ def _train_step_impl(params, opt, version, tokens, behav_logp, mask,
     return params, opt, packed
 
 
-_STEP_STATICS = ("cfg", "rl", "algo", "num_minibatches", "num_microbatches",
-                 "skip_nonfinite")
-# Default engine donates only the optimizer state: the async runtime keeps
-# older params alive as behavior policies (WeightStore / staleness history),
-# so donating them would invalidate live behavior-policy buffers.
-_train_step = jax.jit(_train_step_impl, static_argnames=_STEP_STATICS,
+# The step donates only the optimizer state: the async runtime keeps older
+# params alive as behavior policies (WeightStore / staleness history), so
+# donating them would invalidate live behavior-policy buffers.
+_train_step = jax.jit(_train_step_impl,
+                      static_argnames=("cfg", "rl", "algo", "num_minibatches",
+                                       "num_microbatches", "skip_nonfinite"),
                       donate_argnums=(1,))
-# Opt-in variant for pure synchronous loops that never re-read old params:
-# donates params + opt, letting XLA update weights and moments in place.
-_train_step_donating = jax.jit(_train_step_impl,
-                               static_argnames=_STEP_STATICS,
-                               donate_argnums=(0, 1))
 
 
 # -------------------------------------------------------------------- driver
+def check_trainable(cfg: ModelConfig) -> None:
+    """The step reads token ids only; a stack whose inputs start with a
+    frontend's embeddings (audio codes, image patches) has no train path."""
+    if cfg.frontend is not None:
+        raise ValueError(
+            f"{cfg.name}: the train step reads token ids only, and this "
+            f"stack needs {cfg.frontend!r} frontend embeddings")
+
+
 class Trainer:
     """One training engine. ``step`` = the paper's 'training step'.
 
     ``algo`` selects the policy-optimization algorithm: an ``Algorithm``
     instance from ``core.algorithms``, a registry name, or None (falls
-    back to ``rl.algo`` / the deprecated ``rl.method`` string). The legacy
-    ``method=`` keyword still works but emits a ``DeprecationWarning``.
+    back to ``rl.algo``, then ``a3po``).
 
     ``num_microbatches`` > 1 adds gradient accumulation *inside* the
-    minibatch scan for batches that exceed memory. ``donate_params=True``
-    selects the params-donating compiled step (only safe when no other
-    component holds the previous weights)."""
+    minibatch scan for batches that exceed memory. The trainer trains
+    token-only stacks: a stack with a frontend is refused."""
 
     def __init__(self, cfg: ModelConfig, rl: Optional[RLConfig] = None,
-                 algo=None, *, method: Optional[str] = None,
-                 num_microbatches: int = 1, donate_params: bool = False,
+                 algo=None, *, num_microbatches: int = 1,
                  skip_nonfinite: bool = False):
-        if method is not None:
-            warnings.warn(
-                "Trainer(..., method=...) is deprecated; pass an Algorithm "
-                "or registry name as `algo` (repro.core.algorithms)",
-                DeprecationWarning, stacklevel=2)
-            if algo is None:
-                algo = method
+        check_trainable(cfg)
         self.cfg = cfg
         self.rl = rl or RLConfig()
         self.algo = resolve_algorithm(algo, self.rl)
         self.num_microbatches = num_microbatches
-        self.donate_params = donate_params
         # skip-step guard: non-finite minibatch updates are dropped on
         # device (params/opt unchanged) instead of poisoning the run; the
         # packed `nonfinite` metric counts them (resilience.guards)
         self.skip_nonfinite = skip_nonfinite
         self.last_host_syncs = 0  # host transfers in the most recent step
-
-    @property
-    def method(self) -> str:
-        """Legacy spelling: the resolved algorithm's registry name."""
-        return self.algo.name
 
     def init_state(self, key, dtype=None) -> TrainState:
         """Initialize params + Adam moments, placed with the active
@@ -623,10 +611,8 @@ class Trainer:
         # the host waiting on the device; train_metrics is host bookkeeping
         with span("train_update", algo=self.algo.name,
                   batch=int(B), minibatches=int(nmb)) as update_span:
-            step_fn = (_train_step_donating if self.donate_params
-                       else _train_step)
             with span("train_dispatch"):
-                params, opt, packed = step_fn(
+                params, opt, packed = _train_step(
                     state.params, state.opt, state.version, batch.tokens,
                     batch.behav_logp, batch.response_mask, batch.versions,
                     batch.rewards, prox, cfg=self.cfg, rl=rl,

@@ -12,7 +12,15 @@ from repro.core.a3po import (
     staleness,
 )
 from repro.core.advantages import group_normalized_advantages
-from repro.core.losses import coupled_ppo_loss, decoupled_ppo_loss, policy_loss
+from repro.core.algorithms import LossInputs, get_algorithm
+from repro.core.objective import coupled_ppo_loss, decoupled_ppo_loss
+
+
+def algo_loss(name, logp, behav, adv, mask, cfg, **kw):
+    """The registered algorithm ``name``'s loss over these tensors."""
+    return get_algorithm(name).loss(
+        logp, LossInputs(advantages=adv, mask=mask, behav_logp=behav, **kw),
+        cfg)
 
 
 def test_alpha_schedule_paper_eq4():
@@ -80,9 +88,9 @@ def test_loglinear_d0_recovers_sync():
     mask = jnp.ones((B, T))
     cfg = RLConfig()
     versions = jnp.full((B,), 7, jnp.int32)
-    l_ll, _ = policy_loss("loglinear", logp, behav, adv, mask, cfg,
+    l_ll, _ = algo_loss("a3po", logp, behav, adv, mask, cfg,
                           versions=versions, current_version=7)
-    l_sync, _ = policy_loss("sync", logp, behav, adv, mask, cfg)
+    l_sync, _ = algo_loss("sync", logp, behav, adv, mask, cfg)
     np.testing.assert_allclose(l_ll, l_sync, rtol=1e-6)
 
 
@@ -94,7 +102,7 @@ def test_loss_gradient_direction():
     mask = jnp.ones((1, 1))
 
     def f(logp):
-        l, _ = policy_loss("loglinear", logp, behav, adv, mask, cfg,
+        l, _ = algo_loss("a3po", logp, behav, adv, mask, cfg,
                            versions=jnp.array([2]), current_version=4)
         return l
 
@@ -109,7 +117,7 @@ def test_clipping_activates():
     behav = jnp.array([[-2.0]])
     adv = jnp.array([[1.0]])
     mask = jnp.ones((1, 1))
-    _, m = policy_loss("sync", logp, behav, adv, mask, cfg)
+    _, m = algo_loss("sync", logp, behav, adv, mask, cfg)
     assert float(m["clipped_frac"]) == 1.0
 
 
@@ -129,7 +137,7 @@ def test_metrics_shapes():
     logp = behav = jnp.full((B, T), -1.0)
     adv = jnp.ones((B, T))
     mask = jnp.ones((B, T))
-    _, m = policy_loss("loglinear", logp, behav, adv, mask, cfg,
+    _, m = algo_loss("a3po", logp, behav, adv, mask, cfg,
                        versions=jnp.ones((B,), jnp.int32),
                        current_version=3)
     for k in ("iw_max", "iw_min", "clipped_tokens", "ratio_mean"):
@@ -181,5 +189,5 @@ def test_policy_loss_kl_adaptive_dispatch():
     behav = logp + 0.1
     adv = jnp.ones((B, T))
     mask = jnp.ones((B, T))
-    loss, m = policy_loss("loglinear", logp, behav, adv, mask, cfg)
+    loss, m = algo_loss("a3po", logp, behav, adv, mask, cfg)
     assert np.isfinite(float(loss))

@@ -1,9 +1,7 @@
 """Algorithm-registry tests: built-in coverage and aliases, requires-flags
 contracts, parity of Algorithm objects with the PR-2 scan engine, custom
-registration end-to-end, the deprecation shims over the legacy stringly
-``method`` surface, and the kl_coef wiring."""
+registration end-to-end, and the kl_coef wiring."""
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +26,6 @@ from repro.core.objective import (
     apply_regularizers,
     common_metrics,
     masked_mean,
-    policy_objective,
 )
 from repro.training.trainer import Trainer, TrainState
 
@@ -95,10 +92,9 @@ def test_resolve_algorithm_fallbacks():
     a = A3PO(schedule="exp")
     assert resolve_algorithm(a) is a
     assert resolve_algorithm("sync").name == "sync"
-    # nested per-algorithm config in RLConfig wins over the legacy string
+    # nested per-algorithm config in RLConfig, else the paper default
     assert resolve_algorithm(None, RLConfig(algo=a)) is a
-    assert resolve_algorithm(None, RLConfig(method="recompute")).name \
-        == "recompute"
+    assert resolve_algorithm(None, RLConfig()).name == "a3po"
     assert resolve_algorithm(None, None).name == "a3po"
     with pytest.raises(TypeError):
         resolve_algorithm(42)
@@ -215,49 +211,6 @@ def test_registry_tolerates_plugin_edge_cases():
     assert available() == before
     with pytest.raises(ValueError):
         get_algorithm("test_orphan")
-
-
-# ------------------------------------------------------ deprecation shims
-def test_trainer_method_kwarg_shim(toy, rl):
-    with pytest.warns(DeprecationWarning, match="method"):
-        tr = Trainer(toy, rl, method="loglinear")
-    assert tr.algo.name == "a3po"
-    assert tr.method == "a3po"  # legacy attribute survives
-
-
-def test_policy_objective_string_shim():
-    logp, b = rand_loss_inputs()
-    cfg = RLConfig()
-    kw = dict(versions=b.versions, current_version=b.current_version)
-    with pytest.warns(DeprecationWarning):
-        l1, m1 = policy_objective("loglinear", logp, b.behav_logp,
-                                  b.advantages, b.mask, cfg, **kw)
-    with pytest.warns(DeprecationWarning):
-        l2, _ = policy_objective(method="loglinear", logp=logp,
-                                 behav_logp=b.behav_logp,
-                                 advantages=b.advantages, mask=b.mask,
-                                 cfg=cfg, **kw)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # Algorithm objects must not warn
-        l3, _ = policy_objective(get_algorithm("a3po"), logp, b.behav_logp,
-                                 b.advantages, b.mask, cfg, **kw)
-    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-7)
-    np.testing.assert_allclose(float(l1), float(l3), rtol=1e-7)
-    assert "kl" in m1
-
-
-def test_losses_compat_layer():
-    from repro.core.losses import policy_loss
-    from repro.core import losses as L
-    for sym in ("Algorithm", "LossInputs", "get_algorithm",
-                "resolve_algorithm", "coupled_ppo_loss",
-                "decoupled_ppo_loss", "policy_objective"):
-        assert hasattr(L, sym), sym
-    logp, b = rand_loss_inputs()
-    with pytest.warns(DeprecationWarning):
-        loss, m = policy_loss("sync", logp, b.behav_logp, b.advantages,
-                              b.mask, RLConfig())
-    assert np.isfinite(float(loss))
 
 
 # ------------------------------------------------------------ kl_coef wire

@@ -1,6 +1,7 @@
 """Per-architecture smoke tests: REDUCED variant of each assigned family
-(2 layers, d_model<=512, <=4 experts) runs one forward + one train step on
-CPU with correct shapes and no NaNs; decode consistency vs the full pass.
+(2 layers, d_model<=512, <=4 experts) runs one forward + one Trainer step
+on CPU with correct shapes and no NaNs (frontend stacks are not trained);
+decode consistency vs the full pass.
 """
 import dataclasses
 
@@ -13,7 +14,7 @@ from repro.configs.base import RLConfig
 from repro.configs.registry import ASSIGNED, get_config
 from repro.launch import steps
 from repro.models import model as M
-from repro.training.optimizer import adam_init
+from repro.training.trainer import Trainer, TrainBatch
 
 ARCHS = sorted(ASSIGNED)
 
@@ -57,32 +58,33 @@ def test_forward_shapes_no_nan(arch):
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_train_step_runs(arch):
-    """One full RL train step (fwd + bwd + adam) on the reduced config."""
+    """One full RL train step (fwd + bwd + adam) through ``Trainer.step`` on
+    the reduced config; a frontend stack is refused by name."""
     cfg = _reduced(arch)
-    rl = RLConfig(learning_rate=1e-4)
-    step = steps.make_train_step(cfg, rl, "loglinear", num_microbatches=1)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    opt = adam_init(params)
+    rl = RLConfig(group_size=2, num_minibatches=1, learning_rate=1e-4)
+    if cfg.frontend:
+        with pytest.raises(ValueError, match="frontend"):
+            Trainer(cfg, rl, "a3po")
+        return
+    tr = Trainer(cfg, rl, "a3po")
+    state = tr.init_state(jax.random.PRNGKey(0))
+    # target version 4 against behavior versions 1 and 2: stale rows
+    state = state._replace(version=jnp.int32(4))
     B, S = 2, 16
-    toks, embeds = _inputs(cfg, B, S)
-    batch = {
-        "tokens": toks,
-        "behav_logp": -jnp.ones((B, S - 1)) * 2,
-        "advantages": jax.random.normal(jax.random.PRNGKey(1), (B, S - 1)),
-        "mask": jnp.ones((B, S - 1)),
-        "versions": jnp.array([1, 2], jnp.int32),
-    }
-    if embeds is not None:
-        batch["embeds"] = embeds
-    params2, opt2, loss, entropy, gnorm = jax.jit(
-        step)(params, opt, batch)
-    assert np.isfinite(float(loss)) and np.isfinite(float(gnorm))
-    assert float(entropy) >= 0
+    toks, _ = _inputs(cfg, B, S)
+    batch = TrainBatch(
+        tokens=toks, response_mask=jnp.ones((B, S - 1)),
+        behav_logp=-jnp.ones((B, S - 1)) * 2,
+        versions=jnp.array([1, 2], jnp.int32),
+        rewards=jnp.array([1.0, 0.0]))
+    new, m = tr.step(state, batch)
+    assert np.isfinite(m["loss"]) and np.isfinite(m["grad_norm"])
+    assert m["entropy"] >= 0
     # params actually changed
     diff = jax.tree.reduce(
         lambda a, b: a + b,
         jax.tree.map(lambda a, b: float(jnp.abs(a - b).sum()),
-                     params, params2))
+                     state.params, new.params))
     assert diff > 0
 
 
@@ -109,8 +111,16 @@ def test_input_specs_cover_all_shapes(arch):
     from repro.configs.base import SHAPES
     cfg = get_config(arch)
     for shape in SHAPES.values():
+        if shape.kind == "train" and cfg.frontend:
+            # the Trainer trains token-only stacks
+            with pytest.raises(ValueError, match="frontend"):
+                steps.input_specs(cfg, shape)
+            continue
         specs = steps.input_specs(cfg, shape)
         assert "tokens" in specs
+        if shape.kind == "train":
+            assert set(specs) == {"version", "tokens", "behav_logp", "mask",
+                                  "versions", "rewards"}
         if shape.kind == "decode":
             assert "cache" in specs
             leaves = jax.tree.leaves(

@@ -214,10 +214,10 @@ def test_a3po_loss_kernel_vs_ref(T):
 
 
 def test_a3po_fused_matches_modular_loss():
-    """The fused kernel must agree with core.losses.decoupled_ppo_loss."""
+    """The fused kernel must agree with core.objective.decoupled_ppo_loss."""
     from repro.configs.base import RLConfig
     from repro.core.a3po import compute_prox_logp_approximation
-    from repro.core.losses import decoupled_ppo_loss
+    from repro.core.objective import decoupled_ppo_loss
 
     key = jax.random.PRNGKey(0)
     B, T = 4, 32

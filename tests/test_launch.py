@@ -1,6 +1,8 @@
 """Launch-layer tests: input specs, shardings, lowering on a local mesh,
 and the trip-count-aware HLO cost parser."""
 import dataclasses
+import inspect
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +16,7 @@ from repro.distributed.sharding import ShardingEnv, use_sharding
 from repro.launch import steps
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
+from repro.training import trainer as trainer_mod
 
 
 def test_hlo_cost_plain_matmul():
@@ -57,27 +60,56 @@ def test_hlo_cost_nested_scan():
 
 
 @pytest.mark.parametrize("arch", ["codeqwen1.5-7b", "mamba2-370m",
-                                  "deepseek-v2-lite-16b"])
+                                  "deepseek-v2-lite-16b", "zamba2-1.2b"])
 def test_reduced_train_step_lowers_on_local_mesh(arch):
-    """The dryrun program (shardings included) compiles on the real local
-    mesh for reduced configs — same code path as the 512-device dry-run."""
+    """The dry-run's train program, ``Trainer.step``'s own compiled step
+    (shardings included), compiles on the real local mesh for reduced
+    configs — same code path as the 512-device dry-run."""
     cfg = dataclasses.replace(get_config(arch + "-reduced"), dtype="float32")
-    shape = InputShape("tiny_train", 32, 4, "train")
-    rl = RLConfig()
-    mesh = make_local_mesh()
-    env = ShardingEnv(mesh)
-    specs = steps.input_specs(cfg, shape)
-    step = steps.make_step(cfg, shape, rl, "loglinear")
-    params_abs = M.abstract_params(cfg)
-    param_sh = M.param_shardings(cfg, env)
-    batch_sh = steps.batch_shardings(cfg, shape, env, specs)
-    with mesh, use_sharding(env):
-        opt_abs = steps.abstract_opt_state(params_abs)
-        opt_sh = steps.opt_shardings(param_sh, env)
-        compiled = jax.jit(
-            step, in_shardings=(param_sh, opt_sh, batch_sh)).lower(
-            params_abs, opt_abs, specs).compile()
+    env = ShardingEnv(make_local_mesh())
+    compiled = steps.lower_train_step(cfg, RLConfig(), "a3po", env, 4,
+                                      32).compile()
     assert compiled.cost_analysis() is not None
+    # the step's named phases: the minibatch scan's Adam and the loss
+    scopes = {part for name in re.findall(r'op_name="([^"]*)"',
+                                          compiled.as_text())
+              for part in name.split("/")}
+    assert "optimizer" in scopes and "jvp(policy_loss)" in scopes
+
+
+def test_train_specs_are_the_trainer_step_operands():
+    """A train shape's specs are the operands ``Trainer.step`` passes its
+    compiled step, in order, and ``make_step``'s train step on them is
+    that step: it matches ``Trainer.step`` on the same batch."""
+    impl = inspect.signature(trainer_mod._train_step_impl).parameters
+    names = list(impl)[2:list(impl).index("prox_logp")]
+    cfg = dataclasses.replace(get_config("toy-2m"), dtype="float32")
+    shape = InputShape("tiny_train", 12, 8, "train")
+    specs = steps.input_specs(cfg, shape)
+    assert list(specs) == names
+    rl = RLConfig(group_size=4, num_minibatches=2, learning_rate=1e-3)
+    tr = trainer_mod.Trainer(cfg, rl, "a3po")
+    state = tr.init_state(jax.random.PRNGKey(0))
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    batch = trainer_mod.TrainBatch(
+        tokens=jax.random.randint(ks[0], (8, 12), 4, cfg.vocab_size),
+        response_mask=jnp.ones((8, 11)),
+        behav_logp=-jax.random.uniform(ks[1], (8, 11)),
+        versions=jnp.zeros((8,), jnp.int32),
+        rewards=jax.random.bernoulli(ks[2], 0.5, (8,)).astype(jnp.float32))
+    ops = dict(version=state.version, tokens=batch.tokens,
+               behav_logp=batch.behav_logp, mask=batch.response_mask,
+               versions=batch.versions, rewards=batch.rewards)
+    assert {k: (v.shape, v.dtype) for k, v in ops.items()} == {
+        k: (v.shape, v.dtype) for k, v in specs.items()}
+    step = steps.make_step(cfg, shape, rl, "a3po")
+    p1, _, packed = step(state.params,
+                         jax.tree.map(jnp.copy, state.opt), ops)
+    s2, m = tr.step(state, batch)
+    metrics = dict(zip(trainer_mod.metric_keys(cfg), np.asarray(packed)))
+    assert metrics["loss"] == m["loss"]
+    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(s2.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("arch", ["zamba2-1.2b", "musicgen-large"])
@@ -96,39 +128,6 @@ def test_reduced_decode_step_lowers(arch):
         compiled = jax.jit(step, in_shardings=(param_sh, batch_sh)).lower(
             params_abs, specs).compile()
     assert compiled is not None
-
-
-def test_train_step_microbatch_equivalence():
-    """Gradient accumulation (nm=4) == single batch update (nm=1)."""
-    cfg = dataclasses.replace(get_config("toy-2m"), dtype="float32")
-    rl = RLConfig(learning_rate=1e-3)
-    B, S = 8, 12
-    key = jax.random.PRNGKey(0)
-    params = M.init_params(cfg, key)
-    from repro.training.optimizer import adam_init
-    batch = {
-        "tokens": jax.random.randint(key, (B, S), 4, cfg.vocab_size),
-        "behav_logp": -jnp.ones((B, S - 1)),
-        "advantages": jax.random.normal(jax.random.PRNGKey(1), (B, S - 1)),
-        "mask": jnp.ones((B, S - 1)),
-        "versions": jnp.zeros((B,), jnp.int32),
-    }
-    outs = {}
-    for nm in (1, 4):
-        step = steps.make_train_step(cfg, rl, "loglinear",
-                                     num_microbatches=nm)
-        p2, _, loss, _, gnorm = jax.jit(step)(params, adam_init(params),
-                                              batch)
-        outs[nm] = (p2, float(loss))
-    # losses match exactly; params match to accumulation tolerance.
-    # NOTE: loglinear prox depends on the *microbatch's own* live logp, so
-    # grads differ only via f32 accumulation order.
-    np.testing.assert_allclose(outs[1][1], outs[4][1], rtol=1e-5)
-    a = jax.tree.leaves(outs[1][0])
-    b = jax.tree.leaves(outs[4][0])
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   rtol=5e-3, atol=5e-5)
 
 
 def test_input_specs_no_allocation():

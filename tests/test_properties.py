@@ -15,7 +15,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 from repro.configs.base import RLConfig
 from repro.core.a3po import alpha_from_staleness, compute_prox_logp_approximation
 from repro.core.advantages import group_normalized_advantages
-from repro.core.losses import policy_loss
+from repro.core.algorithms import LossInputs, get_algorithm
 
 logp_arrays = st.lists(
     st.floats(min_value=-20.0, max_value=-1e-3), min_size=1, max_size=32)
@@ -119,9 +119,10 @@ def test_masked_tokens_have_no_gradient_influence(seed):
     logp = jnp.array(-rng.uniform(0.1, 5, (B, T)), jnp.float32)
     garbage = jnp.where(mask > 0, logp, logp * 7 - 3)
     vs = jnp.array(rng.integers(0, 3, B), jnp.int32)
-    l1, _ = policy_loss("loglinear", logp, behav, adv * mask,
-                        jnp.array(mask), cfg, versions=vs, current_version=5)
-    l2, _ = policy_loss("loglinear", garbage, behav, adv * mask,
-                        jnp.array(mask), cfg, versions=vs, current_version=5)
+    a3po = get_algorithm("a3po")
+    batch = LossInputs(advantages=adv * mask, mask=jnp.array(mask),
+                       behav_logp=behav, versions=vs, current_version=5)
+    l1, _ = a3po.loss(logp, batch, cfg)
+    l2, _ = a3po.loss(garbage, batch, cfg)
     if mask.sum() > 0:
         np.testing.assert_allclose(l1, l2, rtol=1e-5, atol=1e-6)

@@ -326,6 +326,18 @@ class TestGuards:
         assert det.update(100.0)
         assert det.update(float("nan"))
 
+    def test_divergence_detector_ignores_noise(self):
+        """A 1 % noisy window, then steps within 3 sigma of it: no trip,
+        the sigma floor stays under real noise."""
+        det = DivergenceDetector(window=8, threshold_sigmas=4.0,
+                                 min_window=4)
+        window = 1.0 + 0.01 * np.random.default_rng(0).standard_normal(8)
+        for x in window:
+            assert not det.update(float(x))
+        mean, std = float(window.mean()), float(window.std())
+        for k in (1.0, 2.0, 3.0):
+            assert not det.update(mean + k * std)
+
     def test_guard_policies(self):
         g = TrainGuard(policy="skip")
         ok = g.after_step({"loss": 1.0, "nonfinite": 0.0})

@@ -10,7 +10,7 @@ import pytest
 
 from repro.configs.base import RLConfig
 from repro.configs.registry import get_config
-from repro.core.algorithms import get_algorithm
+from repro.core.algorithms import LossInputs, get_algorithm
 from repro.core.a3po import (
     alpha_from_staleness,
     compute_prox_logp_approximation,
@@ -21,7 +21,6 @@ from repro.core.objective import (
     coupled_ppo_loss,
     decoupled_ppo_loss,
     fused_a3po_loss,
-    policy_objective,
     resolve_alpha,
 )
 from repro.training import trainer as trainer_mod
@@ -266,10 +265,12 @@ def test_fused_gradient_matches_jnp_reference():
                                rtol=1e-5, atol=1e-7)
 
 
-def test_microbatch_accumulation_matches_single(toy, rl):
-    """num_microbatches=2 (grad accumulation inside the scan) == 1, also
+@pytest.mark.parametrize("nmi", [2, 4])
+def test_microbatch_accumulation_matches_single(toy, rl, nmi):
+    """num_microbatches=nmi (grad accumulation inside the scan) == 1, also
     with heavily skewed response-token counts across microbatches (the
     accumulation is token-weighted, not an equal average of masked means).
+    nmi=4 puts one sequence in each microbatch.
     """
     batch = make_batch(False)
     # skew: rows 0-3 keep ~7 response tokens, rows 4-7 keep exactly one
@@ -281,15 +282,15 @@ def test_microbatch_accumulation_matches_single(toy, rl):
         behav_logp=batch.behav_logp * jnp.asarray(skew))
     for b in (batch, skewed):
         outs = {}
-        for nmi in (1, 2):
-            tr = Trainer(toy, rl, "loglinear", num_microbatches=nmi)
+        for n in (1, nmi):
+            tr = Trainer(toy, rl, "loglinear", num_microbatches=n)
             s = tr.init_state(jax.random.PRNGKey(1))
             s, m = tr.step(s, b)
-            outs[nmi] = (s.params, m)
-        np.testing.assert_allclose(outs[1][1]["loss"], outs[2][1]["loss"],
+            outs[n] = (s.params, m)
+        np.testing.assert_allclose(outs[1][1]["loss"], outs[nmi][1]["loss"],
                                    rtol=1e-5, atol=1e-7)
         for x, y in zip(jax.tree.leaves(outs[1][0]),
-                        jax.tree.leaves(outs[2][0])):
+                        jax.tree.leaves(outs[nmi][0])):
             np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                        rtol=5e-3, atol=5e-5)
 
@@ -301,13 +302,21 @@ def test_microbatch_indivisible_raises(toy, rl):
         tr.step(state, make_batch(False))
 
 
-def test_donating_trainer_chains_steps(toy, rl):
-    """donate_params=True: pure synchronous loop, old state discarded."""
-    tr = Trainer(toy, rl, "sync", donate_params=True)
+def test_step_donates_opt_keeps_params(toy, rl):
+    """The step's contract over 3 chained steps: each step consumes the
+    old Adam state (donated, its buffers deleted) while the old params stay
+    readable, as the async runtime's behavior policies need."""
+    tr = Trainer(toy, rl, "sync")
     state = tr.init_state(jax.random.PRNGKey(0))
-    for _ in range(2):
-        state, m = tr.step(state, make_batch(False))
-    assert int(state.version) == 2
+    for i in range(3):
+        old = state
+        before = [np.asarray(x) for x in jax.tree.leaves(old.params)]
+        state, m = tr.step(state, make_batch(False, seed=i))
+        assert all(x.is_deleted() for x in jax.tree.leaves(old.opt))
+        for x, b in zip(jax.tree.leaves(old.params), before):
+            assert not x.is_deleted()
+            np.testing.assert_array_equal(np.asarray(x), b)
+    assert int(state.version) == 3
     assert np.isfinite(m["loss"])
 
 
@@ -352,26 +361,9 @@ def test_alpha_kl_adaptive_graceful_and_unified_dispatch():
     a2 = resolve_alpha(RLConfig(), versions=jnp.array([1, 1, 3, 3]),
                        current_version=3)
     np.testing.assert_allclose(a2, [0.5, 0.5, 0.0, 0.0])
-    loss, m = policy_objective(get_algorithm("a3po"), logp, behav,
-                               jnp.ones((4, 8)), mask, cfg)
+    loss, m = get_algorithm("a3po").loss(logp, LossInputs(
+        advantages=jnp.ones((4, 8)), mask=mask, behav_logp=behav), cfg)
     assert np.isfinite(float(loss))
-
-
-def test_policy_objective_loglinear_string_still_warns():
-    """The stringly-typed shim stays: 'loglinear' resolves through the
-    registry with a DeprecationWarning and matches the Algorithm call."""
-    key = jax.random.PRNGKey(1)
-    logp = -jax.random.uniform(key, (4, 8)) * 2
-    behav = logp + 0.1
-    adv, mask = jnp.ones((4, 8)), jnp.ones((4, 8))
-    cfg = RLConfig()
-    kw = dict(versions=jnp.array([0, 1, 2, 3]), current_version=3)
-    with pytest.warns(DeprecationWarning, match="stringly-typed"):
-        l_str, _ = policy_objective("loglinear", logp, behav, adv, mask,
-                                    cfg, **kw)
-    l_algo, _ = policy_objective(get_algorithm("a3po"), logp, behav, adv,
-                                 mask, cfg, **kw)
-    np.testing.assert_allclose(float(l_str), float(l_algo), rtol=1e-7)
 
 
 def test_trainer_step_kl_adaptive_end_to_end(toy):
